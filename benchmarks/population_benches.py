@@ -62,20 +62,23 @@ def bench_population_throughput():
     W0 = 8 on CPU."""
     from repro.rl.ga3c import make_rl_objective
     rows = []
-    for w0 in (4, 8, 16):
-        per = {}
-        # thread: tune.py's default node count
+    w0s = (4, 8, 16)
+    # process: tune.py's default node count, OS-process workers over TCP.
+    # These run first, before this process touches jax: a chip belongs to
+    # one process at a time, and each worker needs one
+    spec = {"kind": "rl", "game": "pong",
+            "episodes_per_phase": 10 ** 9, "seed": 0,
+            "max_updates": MAX_UPDATES}
+    procs = {w0: ProcessCluster(4, spec, lease_ttl=30.0,
+                                heartbeat_interval=1.0).run(_policy(w0))
+             for w0 in w0s}
+    for w0 in w0s:
+        per = {"process": procs[w0]}
+        # thread: same node count
         objective = make_rl_objective("pong", episodes_per_phase=10 ** 9,
                                       n_envs=N_ENVS, seed=0,
                                       max_updates=MAX_UPDATES)
         per["thread"] = ThreadCluster(4, objective).run(_policy(w0))
-        # process: same node count, OS-process workers over TCP
-        spec = {"kind": "rl", "game": "pong",
-                "episodes_per_phase": 10 ** 9, "seed": 0,
-                "max_updates": MAX_UPDATES}
-        per["process"] = ProcessCluster(4, spec, lease_ttl=30.0,
-                                        heartbeat_interval=1.0
-                                        ).run(_policy(w0))
         # vectorized: the whole population in one vmapped jitted step.
         # A 1-update throwaway search first pays the one-per-bucket-shape
         # compile; the measured search reuses the cached compiled step.

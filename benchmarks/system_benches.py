@@ -34,7 +34,8 @@ def bench_kernels():
 
     q, k, v = t(1, 256, 4, 64), t(1, 256, 2, 64), t(1, 256, 2, 64)
     us_ref = _time(lambda: flash_attention(q, k, v, use_pallas=False))
-    us_pal = _time(lambda: flash_attention(q, k, v, bq=64, bk=64))
+    us_pal = _time(lambda: flash_attention(q, k, v, bq=64, bk=64,
+                                           interpret=True))
     rows.append(("kernel/flash_attention/ref_us", us_ref,
                  f"pallas_interpret_us={us_pal:.0f}"))
 
@@ -42,7 +43,7 @@ def bench_kernels():
     rows.append(("kernel/rmsnorm/ref_us",
                  _time(lambda: rmsnorm(x, sc, use_pallas=False)),
                  f"pallas_interpret_us="
-                 f"{_time(lambda: rmsnorm(x, sc, use_pallas=True)):.0f}"))
+                 f"{_time(lambda: rmsnorm(x, sc, interpret=True)):.0f}"))
 
     u = t(1, 256, 64)
     dt = jnp.abs(t(1, 256, 64)) * 0.1
@@ -54,7 +55,7 @@ def bench_kernels():
                  _time(lambda: selective_scan(u, dt, a, b, c, dk, h0,
                                               use_pallas=False)),
                  f"pallas_interpret_us="
-                 f"{_time(lambda: selective_scan(u, dt, a, b, c, dk, h0, use_pallas=True, bd=64, bs=64)):.0f}"))
+                 f"{_time(lambda: selective_scan(u, dt, a, b, c, dk, h0, interpret=True, bd=64, bs=64)):.0f}"))
     return rows
 
 

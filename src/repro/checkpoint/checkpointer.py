@@ -15,18 +15,9 @@ import jax
 import numpy as np
 
 
-def _flatten_with_path(tree, is_leaf=None):
-    """jax.tree.flatten_with_path only exists on newer jax; fall back to the
-    stable tree_util spelling on 0.4.x."""
-    fn = getattr(jax.tree, "flatten_with_path", None)
-    if fn is None:
-        fn = jax.tree_util.tree_flatten_with_path
-    return fn(tree, is_leaf=is_leaf)
-
-
 def _flatten(tree) -> dict:
     flat = {}
-    for path, leaf in _flatten_with_path(tree)[0]:
+    for path, leaf in jax.tree.flatten_with_path(tree)[0]:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
                        for p in path)
         flat[key] = np.asarray(leaf)
@@ -48,7 +39,7 @@ def restore(path: str, like: Any) -> Any:
     """Restore into the structure of ``like`` (a pytree of arrays)."""
     with np.load(path) as data:
         flat = {k: data[k] for k in data.files}
-    paths, treedef = _flatten_with_path(like)
+    paths, treedef = jax.tree.flatten_with_path(like)
     leaves = []
     for path_, leaf in paths:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))

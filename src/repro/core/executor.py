@@ -29,6 +29,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
 from repro.core.completion import Bracket
+from repro.launch.runtime import chip_env, count_tpu_chips, repo_root
 from repro.core.service import (AsyncPolicy, Decision, OptimizationService,
                                 TrialStatus)
 
@@ -172,16 +173,39 @@ class ProcessCluster:
             cmd += ["--bracket"]
         return cmd
 
-    def spawn_workers(self, port: int) -> List[subprocess.Popen]:
-        """Launch one worker process per node against a running server."""
-        import repro
-        # namespace package: locate the src dir from __path__, not __file__
-        src_dir = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
+    def worker_envs(self) -> List[Dict[str, str]]:
+        """One environment per worker process.
+
+        A worker of a JAX objective (rl, lm) trains on a TPU chip unless
+        ``JAX_PLATFORMS=cpu`` says otherwise. A chip belongs to one
+        process at a time, so each such worker is shown one chip of its
+        own and runs with ``JAX_PLATFORMS=tpu``: without its chip it
+        fails instead of training on the CPU. More JAX workers than the
+        host has chips is refused here, before any process starts.
+        Synthetic workers import no JAX and are not restricted."""
         env = dict(os.environ)
+        src_dir = os.path.join(repo_root(), "src")
         env["PYTHONPATH"] = src_dir + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        return [subprocess.Popen(self._worker_cmd(port, i), env=env)
+        if (self.objective_spec.get("kind") not in ("rl", "lm")
+                or env.get("JAX_PLATFORMS") == "cpu"):
+            return [env] * self.n_nodes
+        chips = count_tpu_chips()
+        if self.n_nodes > chips:
+            raise RuntimeError(
+                f"{self.n_nodes} {self.objective_spec['kind']} worker "
+                f"processes need one TPU chip each, but this host has "
+                f"{chips}: use at most {chips} node(s) (raise --slots to "
+                "train more trials per worker), or set JAX_PLATFORMS=cpu "
+                "to train the workers on the CPU")
+        return [dict(env, JAX_PLATFORMS="tpu", **chip_env(i))
                 for i in range(self.n_nodes)]
+
+    def spawn_workers(self, port: int) -> List[subprocess.Popen]:
+        """Launch one worker process per node against a running server."""
+        envs = self.worker_envs()
+        return [subprocess.Popen(self._worker_cmd(port, i), env=env)
+                for i, env in enumerate(envs)]
 
     def _await_workers(self, procs, server, svc,
                        journal=None) -> List[int]:
@@ -238,6 +262,7 @@ class ProcessCluster:
         from repro.distributed.journal import Journal, replay_journal
         from repro.distributed.server import MetaoptServer
 
+        self.worker_envs()       # refuse an impossible launch up front
         svc = OptimizationService(policy, bracket_eta=self.bracket_eta)
         # a first-class Scheduler (Hyperband) brings its own brackets:
         # workers must join the barrier even without bracket_eta
@@ -267,11 +292,18 @@ class ProcessCluster:
                                bracket_capacity=bracket_capacity)
         server.start()
         t0 = time.monotonic()
+        procs: List[subprocess.Popen] = []
         try:
             procs = self.spawn_workers(server.port)
             rcs = self._await_workers(procs, server, svc, journal=journal)
             wall = time.monotonic() - t0
         finally:
+            # a launcher that fails or is interrupted leaves no worker
+            # behind holding a chip
+            for p in procs:
+                if p.poll() is None:
+                    p.kill()
+                    p.wait()
             server.stop()
             if journal is not None:
                 journal.close()
@@ -338,6 +370,7 @@ class PopulationCluster:
         self.seed = seed
         self.devices = devices
         self.bracket_eta = bracket_eta
+        self.engine = None      # the last run's engine, for inspection
 
     def run(self, policy: AsyncPolicy) -> ExecResult:
         from repro.population.engine import LocalDriver, PopulationEngine
@@ -358,7 +391,7 @@ class PopulationCluster:
                       or getattr(policy, "w0", None))
             svc.configure_bracket(expect_entrants=(
                 min(slots, budget) if budget else slots))
-        engine = PopulationEngine(
+        engine = self.engine = PopulationEngine(
             self.objective if self.objective is not None else self.game,
             max_slots=slots, n_envs=self.n_envs,
             episodes_per_phase=self.episodes_per_phase,

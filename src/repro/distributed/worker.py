@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.distributed.client import (Pending, RemoteTrial, ServiceClient,
                                       ServiceError)
+from repro.launch.runtime import use_compile_cache
 
 
 # -- objective registry (specs are JSON so they cross process boundaries) ---
@@ -258,6 +259,7 @@ def main(argv=None) -> int:
                     help="tenant id on a multi-tenant server; omit for the "
                          "default (single-search) tenant")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.spec is not None:
         spec = json.loads(args.spec)

@@ -74,7 +74,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_pallas(q, k, v, *, causal=True, window=0, softcap=0.0,
                            q_offset=0, kv_len=None, bq=128, bk=128,
-                           interpret=True):
+                           interpret=False):
     """q: (B, Hq, Sq, hd); k, v: (B, Hkv, Skv, hd). Returns (B, Hq, Sq, hd).
 
     q_offset: absolute position of q[..., 0, :] (static int for the kernel).

@@ -11,7 +11,7 @@ from repro.kernels.flash_attention.ref import chunked_attention
                                    "use_pallas", "interpret", "bq", "bk"))
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, q_offset: int = 0,
-                    use_pallas: bool = True, interpret: bool = True,
+                    use_pallas: bool = True, interpret: bool = False,
                     bq: int = 128, bk: int = 128):
     """q: (B, Sq, Hq, hd); k, v: (B, Skv, Hkv, hd)."""
     if not use_pallas:

@@ -26,7 +26,7 @@ def _gmm_kernel(te_ref, x_ref, w_ref, o_ref):
 
 
 def gmm_pallas(x, w, tile_expert, *, bt: int = 128, bn: int = 128,
-               interpret: bool = True):
+               interpret: bool = False):
     """x: (Tp, D) rows grouped by expert, Tp % bt == 0 and every tile
     single-expert; w: (E, D, F); tile_expert: (Tp//bt,) int32.
     Returns (Tp, F)."""

@@ -8,8 +8,8 @@ from repro.kernels.gmm.gmm import gmm_pallas, pad_groups
 from repro.kernels.gmm.ref import gmm_ref
 
 
-def gmm(x, w, group_sizes, use_pallas: bool = True, interpret: bool = True,
-        bt: int = 128):
+def gmm(x, w, group_sizes, use_pallas: bool = True,
+        interpret: bool = False, bt: int = 128):
     """x: (T, D) rows sorted by expert; w: (E, D, F); group_sizes: (E,).
 
     The Pallas path requires CONCRETE group sizes (it re-tiles the rows) and

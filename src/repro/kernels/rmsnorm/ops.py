@@ -9,7 +9,7 @@ from repro.kernels.rmsnorm.ref import rmsnorm_ref
 
 @partial(jax.jit, static_argnames=("eps", "use_pallas", "interpret"))
 def rmsnorm(x, scale, eps: float = 1e-6, use_pallas: bool = True,
-            interpret: bool = True):
+            interpret: bool = False):
     if use_pallas:
         return rmsnorm_pallas(x, scale, eps=eps, interpret=interpret)
     return rmsnorm_ref(x, scale, eps)

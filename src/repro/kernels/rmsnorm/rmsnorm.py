@@ -20,7 +20,7 @@ def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_pallas(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
-                   block_rows: int = 128, interpret: bool = True):
+                   block_rows: int = 128, interpret: bool = False):
     """x: (..., D); scale: (D,)."""
     orig_shape = x.shape
     d = x.shape[-1]

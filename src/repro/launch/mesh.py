@@ -3,27 +3,20 @@ device state (jax locks the device count on first backend init)."""
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """jax.sharding.AxisType only exists on newer jax; on 0.4.x every mesh
-    axis is implicitly Auto, so omitting the kwarg is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (host) devices exist — tests/smokes."""
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_type_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def force_host_device_count(n: int) -> None:
@@ -46,16 +39,4 @@ def make_population_mesh(slots: int, data: int = 1):
     replicated). Testable on CPU via
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``."""
     return jax.make_mesh((slots, data), ("slots", "data"),
-                         **_axis_type_kwargs(2))
-
-
-def compat_shard_map(body, mesh, in_specs, out_specs):
-    """jax.shard_map/check_vma only exist on newer jax; 0.4.x spells them
-    jax.experimental.shard_map.shard_map/check_rep."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+                         axis_types=(AxisType.Auto,) * 2)

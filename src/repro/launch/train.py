@@ -13,6 +13,7 @@ import jax
 from repro.configs.base import TrainConfig
 from repro.configs.registry import get_config
 from repro.launch.mesh import make_host_mesh
+from repro.launch.runtime import use_compile_cache
 from repro.train.trainer import Trainer
 
 
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -31,6 +31,7 @@ from repro.core.hypertrick import HyperTrick, RandomSearchPolicy
 from repro.core.completion import expected_alpha, min_alpha
 from repro.core.search_space import (LogUniform, SearchSpace, lm_space,
                                      paper_rl_space)
+from repro.launch.runtime import use_compile_cache
 
 
 def synthetic_space() -> SearchSpace:
@@ -120,6 +121,7 @@ def main():
     ap.add_argument("--lease-ttl", type=float, default=15.0)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.objective == "rl":
         space = paper_rl_space()

@@ -83,12 +83,6 @@ def moe_local(cfg: ModelConfig, p, x):
 # ---------------------------------------------------------------------------
 # sharded paths (shard_map)
 # ---------------------------------------------------------------------------
-def _shard_map(body, mesh, in_specs, out_specs):
-    """Version-compat shard_map; shared with the population engine."""
-    from repro.launch.mesh import compat_shard_map
-    return compat_shard_map(body, mesh, in_specs, out_specs)
-
-
 def _expert_parallel_body(cfg: ModelConfig, e_local: int, capacity: int,
                           dp: tuple, p, x):
     """Runs per (data-rank, model-rank). x: (B_local, S, D) replicated over
@@ -237,8 +231,9 @@ def moe_block(cfg: ModelConfig, p, x, mesh=None):
             body = partial(_expert_parallel_a2a_body, cfg, e_local, mp,
                            capacity, dp)
             xspec_in = P(xspec[0], "model", None)
-            fn = _shard_map(body, mesh, (pspecs_a2a(p), xspec_in),
-                            (xspec_in, P()))
+            fn = jax.shard_map(body, mesh=mesh,
+                               in_specs=(pspecs_a2a(p), xspec_in),
+                               out_specs=(xspec_in, P()), check_vma=False)
             return fn(p, x)
         t = b_local * x.shape[1]
         capacity = int(t * cfg.top_k / mp * cfg.capacity_factor) + 1
@@ -250,5 +245,6 @@ def moe_block(cfg: ModelConfig, p, x, mesh=None):
         pspecs["we_down"] = P(None, "model", None)
         body = partial(_tensor_parallel_body, cfg, dp)
 
-    fn = _shard_map(body, mesh, (pspecs, xspec), (xspec, P()))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(pspecs, xspec),
+                       out_specs=(xspec, P()), check_vma=False)
     return fn(p, x)

@@ -301,9 +301,7 @@ def count_params(cfg: ModelConfig, active_only: bool = False,
                  model_shards: int = 1) -> int:
     sch = model_schema(cfg, model_shards)
     total = 0
-    flatten = getattr(jax.tree, "flatten_with_path",
-                      jax.tree_util.tree_flatten_with_path)
-    for path, d in flatten(sch, is_leaf=_is_def)[0]:
+    for path, d in jax.tree.flatten_with_path(sch, is_leaf=_is_def)[0]:
         n = int(np.prod(d.shape))
         keys = "/".join(str(getattr(p, "key", p)) for p in path)
         if active_only and "we_" in keys and cfg.n_experts:

@@ -474,10 +474,10 @@ def _bucket_step(objective: PopulationObjective, structural: Hashable,
 
     if mesh is not None:
         from jax.sharding import PartitionSpec
-        from repro.launch.mesh import compat_shard_map
         spec = PartitionSpec("slots")
-        step = compat_shard_map(step, mesh, (spec,) * (n_traced + 3),
-                                (spec,) * 2)
+        step = jax.shard_map(step, mesh=mesh,
+                             in_specs=(spec,) * (n_traced + 3),
+                             out_specs=(spec,) * 2, check_vma=False)
 
     fn = jax.jit(step, donate_argnums=(0, 1))
     if len(_STEP_CACHE) >= _STEP_CACHE_MAX:

@@ -19,8 +19,11 @@ import threading
 import uuid
 from typing import Optional
 
+import jax
+
 from repro.distributed.client import ServiceClient, ServiceError
 from repro.distributed.protocol import ProtocolError
+from repro.launch.runtime import use_compile_cache
 from repro.population.engine import PopulationEngine, RemoteDriver
 
 
@@ -110,6 +113,7 @@ def main(argv=None) -> int:
 
     if args.bracket and args.eta < 2:
         ap.error("--eta must be >= 2 (demote bottom 1/eta per rung)")
+    use_compile_cache()
     mesh = None
     if args.devices > 1:
         # jax is imported but its backend is not initialized until the
@@ -141,7 +145,9 @@ def main(argv=None) -> int:
             client, engine, heartbeat_interval=args.heartbeat_interval,
             node=args.node)
         n = agent.run()
-    print(f"population worker node={args.node} delivered {n} phase reports "
+    dev = jax.devices()[0]
+    print(f"population worker node={args.node} on {dev.platform} "
+          f"({dev.device_kind}) delivered {n} phase reports "
           f"({engine.total_env_steps} env steps)")
     return 0
 

@@ -48,7 +48,7 @@ def test_flash_attention_vs_oracle(case):
     ref = chunked_attention(q, k, v, causal=causal, window=window,
                             softcap=cap, q_offset=off, chunk=32)
     out = flash_attention(q, k, v, causal=causal, window=window, softcap=cap,
-                          q_offset=off, bq=32, bk=32)
+                          q_offset=off, bq=32, bk=32, interpret=True)
     tol = 2e-2 if dt == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol)
@@ -70,7 +70,7 @@ def test_flash_attention_property(sq, skv, hd, window):
     ref = chunked_attention(q, k, v, causal=True, window=window,
                             q_offset=off, chunk=8)
     out = flash_attention(q, k, v, causal=True, window=window, q_offset=off,
-                          bq=16, bk=16)
+                          bq=16, bk=16, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
 
@@ -90,7 +90,7 @@ def test_selective_scan_vs_ref(B, S, di, st_, bd, bs):
     dk = jnp.ones((di,))
     h0 = _t(B, di, st_, scale=0.2)
     y1, h1 = selective_scan(u, dt, a, b, c, dk, h0, use_pallas=True,
-                            bd=bd, bs=bs)
+                            interpret=True, bd=bd, bs=bs)
     y2, h2 = selective_scan_ref(u, dt, a, b, c, dk, h0)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=2e-5)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=2e-5)
@@ -111,7 +111,7 @@ def test_gmm_vs_ragged_dot(sizes, D, F, bt):
     x = _t(T, D)
     w = _t(E, D, F)
     gs = jnp.asarray(np.array(sizes), jnp.int32)
-    out = gmm(x, w, gs, use_pallas=True, bt=bt)
+    out = gmm(x, w, gs, use_pallas=True, interpret=True, bt=bt)
     ref = gmm_ref(x, w, gs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-4)
 
@@ -128,7 +128,7 @@ def test_gmm_vs_ragged_dot(sizes, D, F, bt):
 def test_rmsnorm_vs_ref(shape, dtype):
     x = _t(*shape, dtype=dtype)
     sc = _t(shape[-1]) + 1.0
-    out = rmsnorm(x, sc, use_pallas=True)
+    out = rmsnorm(x, sc, use_pallas=True, interpret=True)
     ref = rmsnorm_ref(x, sc)
     tol = 5e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),

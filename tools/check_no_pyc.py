@@ -1,4 +1,5 @@
-"""Repo-hygiene guard: no compiled-python artifacts may be git-tracked.
+"""Repo-hygiene guard: no compiled-python artifacts or tool caches may be
+git-tracked.
 
 ``__pycache__``/``*.pyc`` files were purged from the tree once (PR 5) and
 are gitignored, but an ignore rule cannot protect files that are ALREADY
@@ -16,7 +17,8 @@ import subprocess
 import sys
 
 BAD_SUFFIXES = (".pyc", ".pyo", ".pyd")
-BAD_DIR = "__pycache__"
+# bytecode, hypothesis's example database, JAX's compilation cache
+BAD_DIRS = ("__pycache__", ".hypothesis", ".jax_cache")
 
 
 def tracked_artifacts(root: str) -> list:
@@ -26,7 +28,8 @@ def tracked_artifacts(root: str) -> list:
     for path in out.decode().split("\0"):
         if not path:
             continue
-        if path.endswith(BAD_SUFFIXES) or BAD_DIR in path.split("/"):
+        if (path.endswith(BAD_SUFFIXES)
+                or any(d in path.split("/") for d in BAD_DIRS)):
             bad.append(path)
     return bad
 
@@ -35,12 +38,12 @@ def main(argv=None) -> int:
     root = (argv or sys.argv[1:] or ["."])[0]
     bad = tracked_artifacts(root)
     if bad:
-        print("git-tracked python bytecode artifacts (purge with "
+        print("git-tracked bytecode or cache artifacts (purge with "
               "`git rm -r --cached <path>`):")
         for p in bad:
             print(f"  {p}")
         return 1
-    print("no tracked __pycache__/*.pyc artifacts")
+    print("no tracked bytecode or cache artifacts")
     return 0
 
 
