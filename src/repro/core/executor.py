@@ -29,9 +29,9 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
 from repro.core.completion import Bracket
-from repro.launch.runtime import chip_env, count_tpu_chips, repo_root
 from repro.core.service import (AsyncPolicy, Decision, OptimizationService,
                                 TrialStatus)
+from repro.launch.runtime import chip_env, count_tpu_chips, repo_root
 
 
 @dataclass
