@@ -1,0 +1,46 @@
+"""Operations and bytes of a decoder LM training update, reckoned from
+its shapes (``configs/phi3-mini-3.8b-1L.json``).
+
+Operations are the model's: a multiply-add counts two, the forward and
+the backward count three forwards, causal attention counts only the
+keys a query sees, and nothing recomputed is counted. Bytes are the
+least that one update must move: each parameter read by the forward and
+the backward, and the optimizer reading and writing the parameter and
+its two moments, plus reading the gradient. Activations are not counted,
+so both numbers are lower bounds of the work.
+"""
+from __future__ import annotations
+
+
+def matmul_params(cfg: dict) -> int:
+    """Parameters that multiply activations: every layer's projections
+    and the LM head (the embedding is a lookup)."""
+    d, f = cfg["hidden_size"], cfg["intermediate_size"]
+    hd = d // cfg["num_attention_heads"]
+    kv = cfg["num_key_value_heads"] * hd
+    attn = d * d + 2 * d * kv + d * d
+    mlp = 3 * d * f
+    return cfg["num_hidden_layers"] * (attn + mlp) + d * cfg["vocab_size"]
+
+
+def all_params(cfg: dict) -> int:
+    d = cfg["hidden_size"]
+    norms = (2 * cfg["num_hidden_layers"] + 1) * d
+    return matmul_params(cfg) + d * cfg["vocab_size"] + norms
+
+
+def flops_per_token(cfg: dict, seq: int) -> float:
+    """Training operations per token at sequence length ``seq``."""
+    d = cfg["hidden_size"]
+    # causal: query i sees i + 1 keys; scores and values, 2 ops a MAC
+    attn_fwd = 2 * 2 * d * (seq + 1) / 2 * cfg["num_hidden_layers"]
+    return 3 * (2 * matmul_params(cfg) + attn_fwd)
+
+
+def update_bytes(cfg: dict, param_bytes: int = 2,
+                 moment_bytes: int = 4) -> float:
+    """Bytes one slot's update must move, at the least."""
+    n = all_params(cfg)
+    forward_backward = 2 * param_bytes * n
+    optimizer = n * (2 * param_bytes + 4 * moment_bytes + param_bytes)
+    return forward_backward + optimizer

@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of device-op intervals) / window, averaged over the chips."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if t is None or ctx["cell"].kind != "lm":
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
