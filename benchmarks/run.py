@@ -51,8 +51,6 @@ _SLOW_SUITES = [
     ("population_multihost", "multihost_benches",
      "bench_population_multihost"),
     ("population_pbt", "pbt_benches", "bench_population_pbt"),
-    ("telemetry_overhead", "telemetry_benches", "bench_telemetry_overhead"),
-    ("trace_overhead", "trace_benches", "bench_trace_overhead"),
 ]
 _RESULT = "BENCH_SUITE_RESULT "
 

@@ -57,6 +57,7 @@ Two orthogonal extensions ride on the slot axis:
 """
 from __future__ import annotations
 
+import itertools
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, Hashable, List, Optional, Sequence, Tuple
@@ -70,7 +71,7 @@ from repro.population.objectives import (PopulationObjective,
 from repro.population.objectives.ga3c import UNROLL_T_MAX  # noqa: F401
 from repro.rl.ga3c import trial_seed
 from repro.telemetry.metrics import MetricsRegistry
-from repro.telemetry.spans import NULL_RECORDER
+from repro.telemetry.spans import NULL_RECORDER, profiler_span
 
 
 @dataclass(frozen=True)
@@ -606,21 +607,27 @@ class PopulationEngine:
 
     # -- admission ----------------------------------------------------------
     def admit(self, lease: TrialLease, now: float = 0.0) -> None:
-        hp = lease.hparams
-        obj = self.objective
-        key = obj.bucket_key(hp)
-        bucket = self.buckets.get(key)
-        if bucket is None:
-            bucket = self.buckets[key] = Bucket(self, key, 1, hp)
-        i = bucket.free_index()
-        if i is None:
-            i = bucket.capacity
-            bucket.grow(bucket.capacity + 1)
-        rng = jax.random.PRNGKey(trial_seed(self.seed, hp))
-        learner, carry = obj.init_slot_state(rng, hp)
-        meta = SlotMeta(lease.trial_id, hp, bucket.slot_ids[i],
-                        phase_t0=now)
-        bucket.write_slot(i, meta, learner, carry, obj.traced_values(hp))
+        with profiler_span("engine.admit", self.metrics):
+            hp = lease.hparams
+            obj = self.objective
+            key = obj.bucket_key(hp)
+            bucket = self.buckets.get(key)
+            if bucket is None:
+                with profiler_span("engine.grow", self.metrics):
+                    bucket = self.buckets[key] = Bucket(self, key, 1, hp)
+            i = bucket.free_index()
+            if i is None:
+                i = bucket.capacity
+                with profiler_span("engine.grow", self.metrics):
+                    bucket.grow(bucket.capacity + 1)
+            rng = jax.random.PRNGKey(trial_seed(self.seed, hp))
+            with profiler_span("engine.init_slot", self.metrics):
+                learner, carry = obj.init_slot_state(rng, hp)
+            meta = SlotMeta(lease.trial_id, hp, bucket.slot_ids[i],
+                            phase_t0=now)
+            with profiler_span("engine.write_slot", self.metrics):
+                bucket.write_slot(i, meta, learner, carry,
+                                  obj.traced_values(hp))
 
     def _admit_grouped(self, leases: Sequence[TrialLease],
                        now: float) -> None:
@@ -635,10 +642,12 @@ class PopulationEngine:
             free = (bucket.capacity - bucket.n_occupied) if bucket else 0
             need = len(group) - free
             if bucket is None:
-                self.buckets[key] = Bucket(self, key, len(group),
-                                           group[0].hparams)
+                with profiler_span("engine.grow", self.metrics):
+                    self.buckets[key] = Bucket(self, key, len(group),
+                                               group[0].hparams)
             elif need > 0:
-                bucket.grow(bucket.capacity + need)
+                with profiler_span("engine.grow", self.metrics):
+                    bucket.grow(bucket.capacity + need)
             for lease in group:
                 self.admit(lease, now)
 
@@ -654,107 +663,108 @@ class PopulationEngine:
         exhausted = False
         retry_at = 0.0
         poll_at = 0.0
-        while True:
-            now = time.monotonic()
-            want = 0
-            if not exhausted and now >= retry_at:
-                if self.n_occupied < self.max_slots:
-                    want = self.max_slots - self.n_occupied
-                elif (self.speculative_refill and self.bracket_eta
-                      and self.n_active == 0 and self._any_parked()):
-                    # speculative rung-0 refill: the local cohort is fully
-                    # parked; acquire the entrants its demotions will make
-                    # room for BEFORE the verdict polls return, so freed
-                    # slots never idle across the barrier round-trip (the
-                    # service resolves any ready cohort before enrolling
-                    # them, so they land in the next generation)
-                    from repro.core.asha import rung_demotions
-                    want = (self.max_slots
-                            + rung_demotions(self._n_parked(),
-                                             self.bracket_eta)
-                            - self.n_occupied)
-            if want > 0:
-                leases, retry = driver.acquire_many(want,
-                                                    rung=self._rung_hint)
-                if self.n_occupied >= self.max_slots:
-                    self.speculated += len(leases)
-                    self.metrics.counter(
-                        "engine.speculative_leases").inc(len(leases))
-                if leases:
-                    self._admit_grouped(leases, now - t0)
-                elif retry is None:
-                    exhausted = True
-                else:
-                    retry_at = now + retry
-            lost = driver.poll_lost()
-            if lost:
-                self._abandon(lost)
-            if self._any_parked() and (self.n_active == 0 or now >= poll_at):
-                # barrier poll: every parked slot re-sends its withheld
-                # report; the service answers "parked" until the rung
-                # cohort (possibly spanning other hosts) is complete, then
-                # promote/demote come back as continue/stop
-                self._poll_parked(driver, t0)
-                poll_at = now + self.park_poll_interval
-            if self.n_active == 0:
-                if self._any_parked():
-                    # the cohort is waiting on another host — keep leases
-                    # warm and poll again shortly
-                    time.sleep(min(self.park_poll_interval, 0.05))
+        for it in itertools.count():
+            with profiler_span("engine.iteration", self.metrics,
+                               step_num=it):
+                now = time.monotonic()
+                want = 0
+                if not exhausted and now >= retry_at:
+                    if self.n_occupied < self.max_slots:
+                        want = self.max_slots - self.n_occupied
+                    elif (self.speculative_refill and self.bracket_eta
+                          and self.n_active == 0 and self._any_parked()):
+                        # speculative rung-0 refill: the local cohort is
+                        # fully parked; acquire the entrants its demotions
+                        # will make room for BEFORE the verdict polls
+                        # return, so freed slots never idle across the
+                        # barrier round-trip (the service resolves any
+                        # ready cohort before enrolling them, so they land
+                        # in the next generation)
+                        from repro.core.asha import rung_demotions
+                        want = (self.max_slots
+                                + rung_demotions(self._n_parked(),
+                                                 self.bracket_eta)
+                                - self.n_occupied)
+                if want > 0:
+                    with profiler_span("engine.acquire", self.metrics):
+                        leases, retry = driver.acquire_many(
+                            want, rung=self._rung_hint)
+                    if self.n_occupied >= self.max_slots:
+                        self.speculated += len(leases)
+                        self.metrics.counter(
+                            "engine.speculative_leases").inc(len(leases))
+                    if leases:
+                        self._admit_grouped(leases, now - t0)
+                    elif retry is None:
+                        exhausted = True
+                    else:
+                        retry_at = now + retry
+                lost = driver.poll_lost()
+                if lost:
+                    self._abandon(lost)
+                if self._any_parked() and (self.n_active == 0
+                                           or now >= poll_at):
+                    # barrier poll: every parked slot re-sends its withheld
+                    # report; the service answers "parked" until the rung
+                    # cohort (possibly spanning other hosts) is complete,
+                    # then promote/demote come back as continue/stop
+                    self._poll_parked(driver, t0)
+                    poll_at = now + self.park_poll_interval
+                if self.n_active == 0:
+                    if self._any_parked():
+                        # the cohort is waiting on another host — keep
+                        # leases warm and poll again shortly
+                        time.sleep(min(self.park_poll_interval, 0.05))
+                        continue
+                    if exhausted:
+                        break
+                    time.sleep(min(max(retry_at - time.monotonic(), 0.01),
+                                   0.5))
                     continue
-                if exhausted:
-                    break
-                time.sleep(min(max(retry_at - time.monotonic(), 0.01), 0.5))
-                continue
-            iter_t0 = time.perf_counter()
-            for bucket in self.buckets.values():
-                if bucket.n_active:
-                    step_t0 = time.perf_counter()
-                    bucket.step()
-                    if not bucket._stepped:
-                        # first call of this executable shape: dominated by
-                        # trace+compile (dispatch is async, compile is not)
-                        bucket._stepped = True
-                        compile_s = time.perf_counter() - step_t0
-                        self.metrics.histogram("engine.compile_s").observe(
-                            compile_s)
-                        # the compile serves every trial stacked in the
-                        # bucket — critical_path splits it across them
-                        self.spans.end(
-                            "engine.compile", compile_s, cat="engine",
-                            bucket=bucket.key,
-                            trials=[m.trial_id for m in bucket.meta
-                                    if m is not None])
-                    stepped = bucket.n_active
-                    self.total_updates += stepped
-                    self.total_env_steps += stepped * bucket.update_cost
-                    self.metrics.counter("engine.updates").inc(stepped)
-                    self.metrics.counter("engine.env_steps").inc(
-                        stepped * bucket.update_cost)
-            self._poll_phases(driver, t0)
-            self.metrics.histogram("engine.step_s").observe(
-                time.perf_counter() - iter_t0)
-            self.metrics.gauge("engine.slots_active").set(self.n_active)
-            self.metrics.gauge("engine.slots_occupied").set(self.n_occupied)
-            elapsed = time.monotonic() - t0
-            if elapsed > 0:
-                self.metrics.gauge("engine.env_steps_s").set(
-                    self.total_env_steps / elapsed)
+                for bucket in self.buckets.values():
+                    if bucket.n_active:
+                        self._step_bucket(bucket)
+                with profiler_span("engine.poll", self.metrics):
+                    self._poll_phases(driver, t0)
         return self.records
 
-    @staticmethod
-    def _report_many(driver, reports: List[dict]) -> List:
+    def _step_bucket(self, bucket: "Bucket") -> None:
+        """Dispatch one bucket's step and charge its active slots."""
+        step_t0 = time.perf_counter()
+        with profiler_span("engine.dispatch", self.metrics):
+            bucket.step()
+        if not bucket._stepped:
+            # first call of this executable shape: dominated by
+            # trace+compile (dispatch is async, compile is not)
+            bucket._stepped = True
+            compile_s = time.perf_counter() - step_t0
+            self.metrics.histogram("engine.compile_s").observe(compile_s)
+            # the compile serves every trial stacked in the bucket —
+            # critical_path splits it across them
+            self.spans.end("engine.compile", compile_s, cat="engine",
+                           bucket=bucket.key,
+                           trials=[m.trial_id for m in bucket.meta
+                                   if m is not None])
+        stepped = bucket.n_active
+        self.total_updates += stepped
+        self.total_env_steps += stepped * bucket.update_cost
+        self.metrics.counter("engine.updates").inc(stepped)
+        self.metrics.counter("engine.env_steps").inc(
+            stepped * bucket.update_cost)
+
+    def _report_many(self, driver, reports: List[dict]) -> List:
         """Send a generation's reports through the driver — one
         ``report_many`` call when the driver has it (RemoteDriver: one
         wire frame), a per-report loop otherwise (scripted test
         drivers)."""
-        many = getattr(driver, "report_many", None)
-        if many is not None:
-            return many(reports)
-        return [driver.report(r["trial_id"], r["phase"], r["metric"],
-                              r["t_start"], r["t_end"],
-                              env_steps=r.get("env_steps"))
-                for r in reports]
+        with profiler_span("engine.report", self.metrics):
+            many = getattr(driver, "report_many", None)
+            if many is not None:
+                return many(reports)
+            return [driver.report(r["trial_id"], r["phase"], r["metric"],
+                                  r["t_start"], r["t_end"],
+                                  env_steps=r.get("env_steps"))
+                    for r in reports]
 
     def _poll_phases(self, driver, t0: float) -> None:
         # two passes so every slot that finished its phase this iteration
@@ -765,9 +775,10 @@ class PopulationEngine:
         for bucket in self.buckets.values():
             if not bucket.n_active:
                 continue
-            counts, sums = self.objective.progress(bucket.carry)
-            fin_n = np.asarray(counts)
-            fin_sum = np.asarray(sums)
+            with profiler_span("engine.sync", self.metrics):
+                counts, sums = self.objective.progress(bucket.carry)
+                fin_n = np.asarray(counts)
+                fin_sum = np.asarray(sums)
             for i in range(bucket.capacity):
                 meta = bucket.meta[i]
                 if meta is None or not bucket.active[i]:
@@ -781,10 +792,6 @@ class PopulationEngine:
                 t_now = time.monotonic() - t0
                 phase_steps = meta.updates_in_phase * bucket.update_cost
                 phase_s = t_now - meta.phase_t0
-                if phase_s > 0:
-                    self.metrics.histogram(
-                        "engine.phase_env_steps_s").observe(
-                            phase_steps / phase_s)
                 self.spans.end("engine.phase", phase_s, cat="engine",
                                trial_id=meta.trial_id, phase=meta.phase,
                                slot=meta.slot_id)
@@ -917,8 +924,9 @@ class PopulationEngine:
                 continue
             key = id(bucket)
             if key not in counters:
-                counts, sums = self.objective.progress(bucket.carry)
-                counters[key] = (np.asarray(counts), np.asarray(sums))
+                with profiler_span("engine.sync", self.metrics):
+                    counts, sums = self.objective.progress(bucket.carry)
+                    counters[key] = (np.asarray(counts), np.asarray(sums))
             fin_n, fin_sum = counters[key]
             meta.phase += 1
             meta.updates_in_phase = 0

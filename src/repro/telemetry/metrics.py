@@ -1,9 +1,6 @@
 """A minimal in-process metrics registry: counters, gauges, and windowed
-histograms. Stdlib only, one lock per registry, every operation O(1) — the
-whole point is that it can sit inside the service/report and engine/step
-hot paths without moving the throughput needle (see
-``benchmarks/telemetry_benches.py``: instrumented vs uninstrumented engine
-env-steps/s must stay within ~2%).
+histograms. Stdlib only, one lock per registry, every operation O(1), so
+it can sit inside the service/report and engine/step hot paths.
 
 Metrics are created on first use (``registry.counter("service.requeues")``)
 and read as one JSON-able ``snapshot()`` — the payload of the ``stats``
@@ -211,18 +208,25 @@ METRIC_SCHEMA: Dict[str, str] = {
     # -- population/engine.py (the device) ----------------------------------
     "engine.env_steps": "counter — active-lane env transitions",
     "engine.updates": "counter — per-slot train-step executions",
-    "engine.env_steps_s": "gauge — aggregate env-steps/s since engine start",
-    "engine.step_s": "histogram — wall seconds per engine loop iteration",
     "engine.compile_s": ("histogram — first-call (trace+compile) time per "
                          "bucket step executable"),
-    "engine.phase_env_steps_s": ("histogram — per-trial env-steps/s over "
-                                 "each reported phase"),
     "engine.park_stall_s": ("histogram — seconds a slot sat parked at the "
                             "rung barrier"),
     "engine.park_polls": "counter — barrier verdict polls sent",
     "engine.clones": "counter — device-side PBT slot copies executed",
     "engine.speculative_leases": ("counter — leases acquired by "
                                   "speculative rung-0 refill"),
-    "engine.slots_active": "gauge — slots currently training",
-    "engine.slots_occupied": "gauge — slots owned (active + parked)",
+    # -- population/engine.py profiler spans (telemetry.spans.profiler_span):
+    # host seconds per span; .count is the number of times it ran ---------
+    "engine.iteration_s": "histogram — seconds per engine loop pass",
+    "engine.acquire_s": "histogram — seconds per driver acquire_many call",
+    "engine.grow_s": "histogram — seconds per bucket creation or growth",
+    "engine.admit_s": "histogram — seconds per lease admitted",
+    "engine.init_slot_s": "histogram — seconds per init_slot_state call",
+    "engine.write_slot_s": "histogram — seconds per Bucket.write_slot",
+    "engine.dispatch_s": "histogram — seconds per bucket step dispatch",
+    "engine.poll_s": "histogram — seconds per _poll_phases call",
+    "engine.sync_s": ("histogram — seconds per blocking device-to-host "
+                      "progress read"),
+    "engine.report_s": "histogram — seconds per report_many call",
 }

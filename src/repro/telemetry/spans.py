@@ -20,13 +20,19 @@ ignore them. Two layers consume them:
 Hot paths record through a ``SpanRecorder`` (sink = anything with
 ``append(dict)``, i.e. a ``distributed.journal.Journal``); pass
 ``NULL_RECORDER`` for literally zero overhead — the same null-twin
-contract as ``metrics.NULL_REGISTRY``, and the baseline arm of
-``benchmarks/trace_benches.py``.
+contract as ``metrics.NULL_REGISTRY``.
 
 Derived spans (lifecycle, park-waits, cohorts) are NOT recorded on hot
 paths at all: ``derive_spans`` reconstructs them from the acquire / park /
 report / status events the journal already carries, so tracing adds no
 cost where the journal was already paying it.
+
+The engine host loop's per-iteration spans (``engine.iteration``,
+``engine.admit``, ``engine.sync`` ...) never reach the journal. They are
+``profiler_span``s: a ``jax.profiler`` annotation, which lands in the
+profiler's host plane on the same clock as the device planes whenever a
+profiler trace is running, plus the interval's seconds observed into the
+registry histogram ``<name>_s``.
 """
 from __future__ import annotations
 
@@ -99,6 +105,44 @@ class SpanRecorder:
         hot-path form — the caller already timed the interval with
         ``perf_counter`` and needs no extra state."""
         self.record(name, self.clock() - dur, dur, **args)
+
+
+# the engine host loop's profiler spans, each with its registry histogram
+# (named once here, so the hot path formats no string)
+PROFILER_SPANS = ("engine.iteration", "engine.acquire", "engine.grow",
+                  "engine.admit", "engine.init_slot", "engine.write_slot",
+                  "engine.dispatch", "engine.poll", "engine.sync",
+                  "engine.report")
+_HISTOGRAM = {name: name + "_s" for name in PROFILER_SPANS}
+
+
+class profiler_span:
+    """``with profiler_span(name, metrics):`` — a ``jax.profiler``
+    annotation named exactly ``name`` around the block (a
+    ``StepTraceAnnotation`` when ``step_num`` is given), and the block's
+    seconds observed into ``metrics.histogram(name + "_s")`` on exit.
+
+    The annotation is a no-op check unless a profiler trace is running, so
+    the profiler session is the only switch; under ``NULL_REGISTRY`` the
+    annotation is still opened. Nothing is written to the journal. JAX is
+    imported on first use, so importing ``repro.telemetry`` loads none."""
+
+    __slots__ = ("_hist", "_ann", "_t0")
+
+    def __init__(self, name: str, metrics, step_num: Optional[int] = None):
+        from jax import profiler
+        self._hist = metrics.histogram(_HISTOGRAM[name])
+        self._ann = (profiler.TraceAnnotation(name) if step_num is None else
+                     profiler.StepTraceAnnotation(name, step_num=step_num))
+
+    def __enter__(self) -> "profiler_span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._hist.observe(time.perf_counter() - self._t0)
+        self._ann.__exit__(*exc)
 
 
 class _NullRecorder:
@@ -236,6 +280,21 @@ SPAN_SCHEMA: Dict[str, str] = {
                      "(device side of `trial.phase`)"),
     "engine.clone": "device-side PBT slot copy (params + opt state)",
     "engine.park_stall": "a slot parked at the rung barrier, engine side",
+    # -- population/engine.py, profiler only (profiler_span: never in the
+    # journal; each also observes its `<name>_s` registry histogram) -------
+    "engine.iteration": ("one pass of the engine loop, top to end (a step "
+                         "annotation numbered by the pass)"),
+    "engine.acquire": "the driver's acquire_many call",
+    "engine.grow": "creating a bucket or growing its capacity",
+    "engine.admit": "one lease's admission (init_slot, write_slot inside)",
+    "engine.init_slot": "the objective's init_slot_state for one trial",
+    "engine.write_slot": "Bucket.write_slot: the hot-swap into the stack",
+    "engine.dispatch": ("one bucket step: hyperparameter upload and the "
+                        "jitted call (its first call includes the compile)"),
+    "engine.poll": "_poll_phases as a whole (sync and report inside)",
+    "engine.sync": ("one blocking device-to-host read of the progress "
+                    "counters"),
+    "engine.report": "one report_many call, for phase reports or barrier polls",
     # -- derived from ordinary journal events by derive_spans ---------------
     "trial.lifecycle": "acquire to terminal status (one track per trial)",
     "trial.park": "park to barrier release, per parked report",

@@ -21,8 +21,9 @@ from repro.telemetry.critical_path import (BUCKETS, aggregate, attribute,
 from repro.telemetry.export import (build_trace, export_journal,
                                     validate_chrome_trace)
 from repro.telemetry.export import main as export_main
-from repro.telemetry.spans import (NULL_RECORDER, SPAN_SCHEMA, Span,
-                                   SpanRecorder, derive_spans)
+from repro.telemetry.spans import (NULL_RECORDER, PROFILER_SPANS,
+                                   SPAN_SCHEMA, Span, SpanRecorder,
+                                   derive_spans)
 
 
 def _space():
@@ -429,5 +430,6 @@ def test_dashboard_once_appends_critical_path_table(replay_journal, capsys):
 def test_span_schema_covers_recorded_and_derived_names():
     assert {"rpc.<verb>", "trial.phase", "engine.compile", "engine.phase",
             "engine.clone", "engine.park_stall", "trial.lifecycle",
-            "trial.park", "cohort.rung"} == set(SPAN_SCHEMA)
+            "trial.park", "cohort.rung"} | set(PROFILER_SPANS) \
+        == set(SPAN_SCHEMA)
     assert all(isinstance(v, str) and v for v in SPAN_SCHEMA.values())
