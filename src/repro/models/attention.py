@@ -4,6 +4,11 @@ The chunked path scans over KV blocks with an online-softmax running state so
 no (Sq, Skv) score tensor ever materializes for long sequences — this is also
 the pure-jnp oracle for the Pallas flash_attention kernel.
 
+A training forward goes through ``train_attention``: on a TPU, causal MHA
+self-attention whose shapes the fused Pallas kernel accepts runs that kernel
+(forward and backward); everything else, and every other platform, runs
+``chunked_attention``.
+
 Decode (Sq == 1) uses a single unchunked pass: scores are (B, H, 1, Skv),
 linear in cache length, and SPMD handles sequence-sharded caches via partial
 max/sum reductions.
@@ -16,6 +21,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.flash_attention.train import (flash_attention_train,
+                                                 train_block_sizes)
 from repro.models import flags
 
 NEG_INF = -1e30
@@ -125,6 +132,50 @@ def chunked_attention(
     out = acc / jnp.maximum(l, 1e-30)[..., None]                # (B,Hkv,G,Sq,hd)
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, Hq, hd)
     return out.astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# training forward: fused kernel on TPU where the shapes allow
+# ---------------------------------------------------------------------------
+def fused_block_sizes(q_shape, k_shape, *, causal, window, softcap, q_offset,
+                      mesh=None):
+    """The fused kernel's blocks where a training call may take it, else
+    None: causal self-attention (Sq == Skv, static offset 0), no softcap,
+    no window narrower than the sequence, Hq == Hkv, hd <= 128 or a
+    multiple of 128, a sequence some block divides, and no mesh (the
+    kernel is one device's program; XLA cannot partition it)."""
+    _, S, Hq, hd = q_shape
+    _, Skv, Hkv, _ = k_shape
+    if not (causal and S == Skv and isinstance(q_offset, int)
+            and q_offset == 0 and not softcap
+            and (window == 0 or window >= S) and Hq == Hkv
+            and (hd <= 128 or hd % 128 == 0) and mesh is None):
+        return None
+    return train_block_sizes(S)
+
+
+def train_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
+                    q_offset=0, chunk=512, mesh=None):
+    """Attention of a training forward, (B, S, H, hd) in and out. The
+    platform is decided at lowering, so a compile for a described TPU
+    takes the kernel and every other platform lowers the chunked scan."""
+    def chunked(q, k, v):
+        with jax.named_scope("attention.chunked"):
+            return chunked_attention(q, k, v, causal=causal, window=window,
+                                     softcap=softcap, q_offset=q_offset,
+                                     chunk=chunk)
+
+    blocks = fused_block_sizes(q.shape, k.shape, causal=causal,
+                               window=window, softcap=softcap,
+                               q_offset=q_offset, mesh=mesh)
+    if blocks is None:
+        return chunked(q, k, v)
+
+    def fused(q, k, v):
+        with jax.named_scope("attention.flash"):
+            return flash_attention_train(q, k, v, blocks)
+
+    return jax.lax.platform_dependent(q, k, v, tpu=fused, default=chunked)
 
 
 def _make_mask(q_pos, kv_pos, causal, window, kv_valid_len):

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.models.attention import chunked_attention, rope
+from repro.models.attention import chunked_attention, rope, train_attention
 
 
 # ---------------------------------------------------------------------------
@@ -92,7 +92,11 @@ def attn_block(cfg: ModelConfig, p, x, *, mode: str, pos, cache,
         k = rope(k, positions, cfg.rope_theta)
 
     new_cache = None
-    if mode == "train" or cache is None:
+    if mode == "train":
+        out = train_attention(
+            q, k, v, causal=causal, window=window, softcap=cfg.attn_softcap,
+            q_offset=pos, chunk=cfg.attn_chunk, mesh=mesh)
+    elif cache is None:
         out = chunked_attention(
             q, k, v, causal=causal, window=window, softcap=cfg.attn_softcap,
             q_offset=pos, chunk=cfg.attn_chunk)

@@ -9,6 +9,7 @@ The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and
 every test worker imports this file.
 """
+import json
 import os
 
 import numpy as np
@@ -84,6 +85,26 @@ def test_lm_bucket_step_compiles_for_one_chip(one_chip):
     from repro.population.objectives.lm import LMObjective
     compiled = _compile_bucket(LMObjective("yi-9b"), 32, 2, one_chip)
     assert compiled.memory_analysis() is not None
+
+
+def _phi3_1l_objective(seq):
+    """The benchmark's ``phi3-mini-3.8b-1L`` objective at ``seq`` tokens."""
+    from bench.kinds.lm import build_objective
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "bench",
+                        "configs", "phi3-mini-3.8b-1L.json")
+    with open(path) as f:
+        config = json.load(f)
+    return build_objective(config, {"batch": 1, "seq": seq})
+
+
+def test_phi3_1l_bucket_step_runs_the_flash_kernel(one_chip):
+    """Two slots of 1 x 2048 tokens at Phi-3-mini widths (32 heads of 96,
+    MHA, causal): attention takes the Pallas kernel, whose working set is
+    a fraction of the chunked scan's float32 score blocks (6.65 GiB of
+    temporaries with the scan)."""
+    compiled = _compile_bucket(_phi3_1l_objective(2048), 1024, 2, one_chip)
+    _assert_kernel(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
 
 
 def test_ga3c_bucket_step_shard_maps_over_four_chips(topo):
