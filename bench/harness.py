@@ -11,8 +11,32 @@ Everything particular to a cell lives in files found by name:
   phases, eviction, phase length and budget;
 * ``bench/kinds/<kind>.py``        how the program's objective of that
   model kind is built and where its state keeps what is compared;
+* ``bench/flops/<kind>.py``        the operations and bytes of that kind's
+  work, reckoned from the configuration's shapes;
 * ``bench/metrics/<metric>.py``    one reader per metric named in
   ``BENCHMARK.json``.
+
+A kind module provides ``build_objective(config, traffic)`` (the
+program's objective for the configuration file and traffic mix),
+``engine_kwargs(config, traffic)`` (further arguments of
+``PopulationEngine``), ``params(learner)`` (the weights in a bucket's
+stacked learner), ``grad_moment(learner, config)`` (an optimizer state
+leaf tree and the factor that turn it into the squared first gradient
+after one step), ``counter(learner)`` (each slot's optimizer step
+count) and ``loss_sum(carry)`` (each slot's summed ``-loss``, or None).
+A kind of the LM family reuses ``kinds/lm.py``: it builds the program's
+``ModelConfig`` itself and hands it to that module's ``objective(cfg,
+config, traffic)``, and takes the other functions from it, loading it
+with ``load_module(os.path.join(os.path.dirname(__file__), "lm.py"),
+"bench_kind_lm")``, which gives the module object the harness holds.
+
+A flops module provides, for the configuration file ``cfg``:
+``flops_per_token(cfg, seq)`` (training operations a token, nothing
+recomputed), ``update_bytes(cfg)`` (the least bytes one slot's update
+moves) and ``attention_work(cfg, batch, seq)`` (operations and least
+bytes of the attention, forward and backward, over ``batch`` sequences).
+A metric's readers use what they need of it, and a metric reaches only
+the cells its ``workloads`` list in ``BENCHMARK.json`` names.
 
 The run drives ``PopulationEngine.run`` through the program's own
 ``LocalDriver``, wrapped so that the harness sees each engine iteration

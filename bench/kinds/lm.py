@@ -1,11 +1,15 @@
 """The LM model kind: the program's ``LMObjective`` around the model
 configuration of the file.
 
-``LMObjective.__init__`` builds ``get_config(arch).reduced()``; the
-subclass below runs it, then puts in its place the configuration the file
-states (the registry's architecture at its published widths, cut in
-depth) and the data table at that vocabulary, drawn as the program draws
-it. The engine, the step and the model code are the program's own.
+``LMObjective.__init__`` builds ``get_config(arch).reduced()``;
+``objective`` runs it, then puts in its place the program's
+``ModelConfig`` it is given and the data table at that vocabulary, drawn
+as the program draws it. The engine, the step and the model code are the
+program's own. ``model_config`` states this kind's configuration: the
+registry's dense architecture at the file's widths, cut in depth. A kind
+of the same family (``bench/kinds/<kind>.py``) builds its own
+``ModelConfig`` and reuses ``objective``, ``params``, ``grad_moment``,
+``counter`` and ``loss_sum`` from this module (see ``harness.py``).
 """
 from __future__ import annotations
 
@@ -36,7 +40,12 @@ def model_config(config: dict):
     return cfg
 
 
-def build_objective(config: dict, traffic: dict):
+def objective(cfg, config: dict, traffic: dict):
+    """The program's ``LMObjective`` training the program's ``ModelConfig``
+    ``cfg`` on the traffic's batch and sequence, its optimizer checked
+    against what the file ``config`` states. ``cfg.name`` is an
+    architecture of the program's registry, which ``LMObjective.__init__``
+    looks up before ``cfg`` takes its place."""
     import jax.numpy as jnp
     from repro.population.objectives.lm import LMObjective
 
@@ -52,8 +61,8 @@ def build_objective(config: dict, traffic: dict):
         def cache_key(self):
             return ("lm", self.cfg, self.batch, self.seq, self.data_seed)
 
-    obj = LMAtConfig(model_config(config), int(traffic["batch"]),
-                     int(traffic["seq"]), int(config["data_seed"]))
+    obj = LMAtConfig(cfg, int(traffic["batch"]), int(traffic["seq"]),
+                     int(config["data_seed"]))
     tc = obj.tc
     stated = (config["optimizer"], config["adam_b1"], config["adam_b2"],
               config["weight_decay"])
@@ -62,6 +71,10 @@ def build_objective(config: dict, traffic: dict):
         raise ValueError(f"{config['name']}: the program's optimizer is "
                          f"{runs}, the configuration states {stated}")
     return obj
+
+
+def build_objective(config: dict, traffic: dict):
+    return objective(model_config(config), config, traffic)
 
 
 def engine_kwargs(config: dict, traffic: dict) -> dict:

@@ -4,6 +4,6 @@
 
 def read(ctx):
     t = ctx["trace"]
-    if t is None or ctx["cell"].kind != "lm":
+    if t is None:
         return None
     return 100.0 * (1.0 - t["busy_s"] / t["window_s"])

@@ -3,6 +3,4 @@
 
 
 def read(ctx):
-    if ctx["cell"].kind != "lm":
-        return None
     return ctx["work_per_s"]

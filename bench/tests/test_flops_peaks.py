@@ -35,6 +35,29 @@ def test_lm_operations_and_bytes_by_hand():
     assert lm.update_bytes(cfg) == 1176 * (4 + 22)
 
 
+def test_lm_attention_work_by_hand():
+    cfg = {"hidden_size": 8, "num_attention_heads": 2,
+           "num_hidden_layers": 3}
+    ops, moved = flops("lm").attention_work(cfg, 5, 4)
+    # 3 layers x 3 forwards x 2 * 8 * (4 + 1) a token x 20 tokens
+    assert ops == 3 * 3 * 80 * 20
+    # 3 layers x (12 bf16 tensors of 20 x 8 + an f32 per token and head,
+    # written and read)
+    assert moved == 3 * (12 * 2 * 8 * 20 + 2 * 4 * 2 * 20)
+
+
+def test_lm_attention_work_at_the_cell():
+    """Two slots of 2048 tokens at Phi-3-mini widths: 2 * 3072 * 2049
+    operations a token forward, three forwards, 4096 tokens; twelve bf16
+    tensors of 2048 x 3072 a slot."""
+    cfg = harness.read_json(ROOT, "bench/configs/phi3-mini-3.8b-1L.json")
+    ops, moved = flops("lm").attention_work(cfg, 2, 2048)
+    assert ops == 3 * 2 * 3072 * 2049 * 4096
+    assert ops == pytest.approx(154.7e9, rel=1e-3)
+    assert moved == 12 * 2 * 2048 * 3072 * 2 + 2 * 4 * 32 * 4096
+    assert moved == pytest.approx(302e6, rel=5e-3)
+
+
 def test_peaks_are_keyed_by_device_kind():
     v5e = harness.peaks(ROOT, "TPU v5 lite")
     assert v5e["bf16_flops_per_s"] == 197e12

@@ -14,14 +14,22 @@ feed it a trace written by hand.
 * Busy time is the union of the op intervals inside the window; idle is
   the rest of the window.
 * Executable time is read from the ``XLA Modules`` line: the events whose
-  name starts with a given prefix (the bucket step's is ``jit_step``).
+  name starts with a given prefix (the bucket step's is ``jit_step``),
+  counted where they start inside the window. The ops that run inside
+  those calls are also totalled on their own, so that an op's time a
+  step is their total over the calls, with no part of a call cut off at
+  the window's edges.
 * An idle gap of device 0 is named after the host span of the harness
   (``acquire``, ``report``, ...) that overlaps it most, and ``engine host``
   where none does.
+* A kernel's time (``kernel_s``) is read from such totals by the HLO
+  instruction's name, the text before `` = ``: a Pallas kernel's
+  instruction carries the kernel's name (``%flash_mha_bwd_dq_... = ...``).
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence, Tuple
+import bisect
+from typing import Dict, List, Optional, Sequence, Tuple
 
 Event = Tuple[str, float, float]          # (name, start_ns, dur_ns)
 
@@ -114,13 +122,20 @@ def _label(gap, spans) -> str:
     return best
 
 
+def _most_first(ns: Dict[str, float], n: int) -> List[Tuple[str, float]]:
+    """``(name, seconds)`` averaged over ``n`` devices, most first."""
+    return sorted(((k, v * 1e-9 / n) for k, v in ns.items()),
+                  key=lambda x: -x[1])
+
+
 def reduce_trace(planes: List[dict], step_prefix: str = "jit_step",
                  host_spans: Sequence[str] = ()) -> dict:
     """``busy_s`` and ``window_s`` (busy averaged over the device planes),
     ``step_calls`` and ``step_s`` (the step executable's calls and device
     seconds, averaged over devices), ``ops`` (op name -> device seconds
-    averaged over devices, most first) and ``gaps`` (device 0's idle gaps,
-    longest first, as ``(label, seconds)``)."""
+    averaged over devices, most first), ``step_ops`` (the same, of the ops
+    inside those step calls, wherever they fall) and ``gaps`` (device 0's
+    idle gaps, longest first, as ``(label, seconds)``)."""
     lo, hi = _window(planes)
     devices = sorted((p for p in planes if is_device(p["name"])),
                      key=lambda p: p["name"])
@@ -131,19 +146,27 @@ def reduce_trace(planes: List[dict], step_prefix: str = "jit_step",
              if name in host_spans]
     busy_total, step_calls, step_ns = 0.0, 0, 0.0
     op_ns: Dict[str, float] = {}
+    step_op_ns: Dict[str, float] = {}
     idle = []
     for k, plane in enumerate(devices):
         ops = _ops(plane)
         busy = union(clip([(s, s + d) for _, s, d in ops], lo, hi))
         busy_total += sum(e - s for s, e in busy)
-        for name, s, d in ops:
-            if lo <= s < hi:
-                name = name[:OP_NAME_CHARS]
-                op_ns[name] = op_ns.get(name, 0.0) + d
+        calls = []
         for name, s, d in plane["lines"].get(MODULES_LINE, []):
             if name.startswith(step_prefix) and lo <= s < hi:
                 step_calls += 1
                 step_ns += d
+                calls.append((s, s + d))
+        calls.sort()
+        starts = [s for s, _ in calls]
+        for name, s, d in ops:
+            name = name[:OP_NAME_CHARS]
+            if lo <= s < hi:
+                op_ns[name] = op_ns.get(name, 0.0) + d
+            i = bisect.bisect_right(starts, s) - 1
+            if i >= 0 and s < calls[i][1]:
+                step_op_ns[name] = step_op_ns.get(name, 0.0) + d
         if k == 0:
             idle = gaps(busy, lo, hi)
     n = len(devices)
@@ -155,7 +178,22 @@ def reduce_trace(planes: List[dict], step_prefix: str = "jit_step",
         "devices": n,
         "step_calls": step_calls / n,
         "step_s": step_ns * 1e-9 / n,
-        "ops": sorted(((k, v * 1e-9 / n) for k, v in op_ns.items()),
-                      key=lambda x: -x[1]),
+        "ops": _most_first(op_ns, n),
+        "step_ops": _most_first(step_op_ns, n),
         "gaps": labelled,
     }
+
+
+def instruction(op: str) -> str:
+    """The HLO instruction's name in an op's name, which is its HLO text
+    (``%fusion.3 = bf16[...] fusion(...)`` -> ``fusion.3``)."""
+    return op.split(" = ", 1)[0].lstrip("%")
+
+
+def kernel_s(ops: Sequence[Tuple[str, float]], names: Sequence[str]
+             ) -> Optional[float]:
+    """Device seconds of the ops (``(name, seconds)``, as ``reduce_trace``
+    gives them) whose instruction name holds any of ``names``; None where
+    no op does."""
+    found = [s for op, s in ops if any(n in instruction(op) for n in names)]
+    return sum(found) if found else None
