@@ -15,8 +15,11 @@ from typing import Optional, Sequence, Tuple
 # ---------------------------------------------------------------------------
 # A model is a repeated *pattern* of (mixer, ffn) blocks, scanned over
 # ``n_repeat`` repetitions (scan-over-layers keeps compile time O(1) in depth).
-#   mixer: 'attn' | 'attn_local' | 'attn_global' | 'mamba' | 'mlstm' | 'slstm'
+#   mixer: 'attn' | 'attn_local' | 'attn_global' | 'mla' | 'mamba' | 'mlstm'
+#          | 'slstm'
 #   ffn:   'mlp' | 'moe' | None
+# ``first_dense`` leading (pattern[0] mixer, 'mlp') layers run ahead of the
+# scanned pattern (DeepSeek-V3's ``first_k_dense_replace``).
 BlockSpec = Tuple[str, Optional[str]]
 
 
@@ -44,6 +47,15 @@ class ModelConfig:
     final_softcap: float = 0.0
     attn_chunk: int = 512            # kv chunk for flash-style chunked attention
 
+    # latent attention (mixer 'mla', DeepSeek-V2/V3): K and V come from a
+    # normed latent of width kv_lora_rank; q/k heads have qk_nope_head_dim
+    # position-free dims plus qk_rope_head_dim RoPE dims (one RoPE key head
+    # shared by all heads); v heads have v_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
     # MoE
     n_experts: int = 0
     top_k: int = 0
@@ -51,6 +63,13 @@ class ModelConfig:
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
     moe_impl: str = "psum"           # psum (baseline) | a2a (perf iteration)
+    router: str = "softmax"          # softmax (+ Switch aux) | sigmoid (+ bias)
+    routed_scale: float = 1.0        # gates x this (routed_scaling_factor)
+    n_shared_experts: int = 0        # one shared MLP of n x moe_d_ff, every token
+    experts_held: int = 0            # experts this device holds (0 -> all)
+    expert_offset: int = 0           # first expert id held
+    remat_moe: bool = False          # recompute each MoE layer in the
+                                     # backward (its T*k-row buffers)
 
     # SSM (mamba)
     ssm_d_state: int = 16
@@ -71,6 +90,7 @@ class ModelConfig:
 
     # norms / activations
     norm: str = "rmsnorm"            # rmsnorm | layernorm
+    norm_eps: float = 1e-6
     norm_f32: bool = True            # False: norm stats in input dtype (perf)
     seq_parallel: bool = False       # shard residual stream over 'model' (SP)
     act: str = "silu"                # silu (SwiGLU) | gelu (plain MLP, whisper)
@@ -82,6 +102,8 @@ class ModelConfig:
     # full-attention arch (documented deviation; 0 = native support or skip)
     long_context_window: int = 0
 
+    first_dense: int = 0             # leading dense layers (see BlockSpec)
+
     dtype: str = "bfloat16"
     use_pallas: bool = False         # kernels are TPU-targeted; refs used on CPU
 
@@ -89,13 +111,29 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
-        assert self.n_layers % len(self.pattern) == 0, (
-            f"{self.name}: n_layers={self.n_layers} not divisible by "
-            f"pattern length {len(self.pattern)}")
+        assert (self.n_layers - self.first_dense) % len(self.pattern) == 0, (
+            f"{self.name}: n_layers={self.n_layers} less first_dense="
+            f"{self.first_dense} not divisible by pattern length "
+            f"{len(self.pattern)}")
 
     @property
     def n_repeat(self) -> int:
-        return self.n_layers // len(self.pattern)
+        return (self.n_layers - self.first_dense) // len(self.pattern)
+
+    @property
+    def first_pattern(self) -> Tuple[BlockSpec, ...]:
+        """The block of the ``first_dense`` leading layers: the pattern's
+        first mixer and a dense MLP."""
+        return ((self.pattern[0][0], "mlp"),)
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
     @property
     def ssm_d_inner(self) -> int:
@@ -155,9 +193,12 @@ class ModelConfig:
                 seen.add(blk[0])
                 mixers.append(blk)
         pattern = tuple(mixers[:4]) or pattern
+        n_experts = min(self.n_experts, 4)
+        first_dense = min(self.first_dense, 1)
         return dataclasses.replace(
             self,
-            n_layers=len(pattern),
+            n_layers=first_dense + len(pattern),
+            first_dense=first_dense,
             d_model=d_model,
             n_heads=n_heads,
             n_kv_heads=n_kv,
@@ -165,8 +206,14 @@ class ModelConfig:
             d_ff=min(self.d_ff, 512) if self.d_ff else 0,
             moe_d_ff=min(self.expert_d_ff, 256) if self.n_experts else 0,
             vocab_size=min(self.vocab_size, 512),
-            n_experts=min(self.n_experts, 4),
+            n_experts=n_experts,
             top_k=min(self.top_k, 2),
+            experts_held=min(self.experts_held, n_experts),
+            expert_offset=0,
+            kv_lora_rank=min(self.kv_lora_rank, 32),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 32),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 16),
+            v_head_dim=min(self.v_head_dim, 32),
             pattern=pattern,
             n_enc_layers=min(self.n_enc_layers, 1),
             enc_seq=min(self.enc_seq, 16) if self.enc_seq else 0,

@@ -39,5 +39,5 @@ def _load_all():
     from repro.configs import (  # noqa: F401
         whisper_large_v3, llava_next_34b, jamba_v0_1_52b, grok_1_314b,
         starcoder2_3b, yi_9b, xlstm_1_3b, kimi_k2_1t_a32b, gemma2_2b,
-        phi3_mini_3_8b, a3c_atari)
+        phi3_mini_3_8b, a3c_atari, moonlight_16b_a3b)
     _LOADED = True

@@ -46,11 +46,13 @@ def train_block_sizes(seq: int) -> Optional[BlockSizes]:
 
 
 def flash_attention_train(q: jax.Array, k: jax.Array, v: jax.Array,
-                          blocks: BlockSizes) -> jax.Array:
+                          blocks: BlockSizes,
+                          scale: Optional[float] = None) -> jax.Array:
     """Causal self-attention, q, k, v: (B, S, H, hd) -> (B, S, H, hd),
-    scaled by ``hd ** -0.5``; differentiable."""
+    scaled by ``scale`` (default ``hd ** -0.5``); differentiable."""
     t = lambda x: x.transpose(0, 2, 1, 3)
     out = flash_attention(t(q), t(k), t(v), causal=True,
-                          sm_scale=q.shape[-1] ** -0.5,
+                          sm_scale=q.shape[-1] ** -0.5 if scale is None
+                          else scale,
                           block_sizes=blocks)
     return t(out)

@@ -66,11 +66,16 @@ def chunked_attention(
     kv_positions: Optional[jax.Array] = None,  # (Skv,) absolute, default iota
     kv_valid_len=None,          # mask k beyond this length (decode w/ prealloc)
     chunk: int = 512,
+    scale: Optional[float] = None,  # None -> hd ** -0.5
 ) -> jax.Array:
+    """v may be narrower than q and k (latent attention): the output
+    takes v's width."""
     B, Sq, Hq, hd = q.shape
     _, Skv, Hkv, _ = k.shape
+    dv = v.shape[-1]
     G = Hq // Hkv
-    scale = hd ** -0.5
+    if scale is None:
+        scale = hd ** -0.5
     qf = (q.astype(jnp.float32) * scale).reshape(B, Sq, Hkv, G, hd)
     q_pos = q_offset + jnp.arange(Sq)
 
@@ -81,12 +86,12 @@ def chunked_attention(
         # single pass (decode or short kv)
         return _attend_block(
             qf, k, v, q_pos, kv_positions, causal, window, softcap,
-            kv_valid_len).astype(q.dtype).reshape(B, Sq, Hq, hd)
+            kv_valid_len).astype(q.dtype).reshape(B, Sq, Hq, dv)
 
     n_chunks = Skv // chunk
     rem = Skv - n_chunks * chunk
     kc = k[:, : n_chunks * chunk].reshape(B, n_chunks, chunk, Hkv, hd)
-    vc = v[:, : n_chunks * chunk].reshape(B, n_chunks, chunk, Hkv, hd)
+    vc = v[:, : n_chunks * chunk].reshape(B, n_chunks, chunk, Hkv, dv)
     pc = kv_positions[: n_chunks * chunk].reshape(n_chunks, chunk)
 
     def body(carry, xs):
@@ -108,7 +113,7 @@ def chunked_attention(
 
     m0 = jnp.full((B, Hkv, G, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, Hkv, G, Sq), jnp.float32)
-    a0 = jnp.zeros((B, Hkv, G, Sq, hd), jnp.float32)
+    a0 = jnp.zeros((B, Hkv, G, Sq, dv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         body, (m0, l0, a0),
         (kc.transpose(1, 0, 2, 3, 4), vc.transpose(1, 0, 2, 3, 4), pc),
@@ -130,7 +135,7 @@ def chunked_attention(
         m = m_new
 
     out = acc / jnp.maximum(l, 1e-30)[..., None]                # (B,Hkv,G,Sq,hd)
-    out = out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, Hq, hd)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, Hq, dv)
     return out.astype(q.dtype)
 
 
@@ -141,29 +146,43 @@ def fused_block_sizes(q_shape, k_shape, *, causal, window, softcap, q_offset,
                       mesh=None):
     """The fused kernel's blocks where a training call may take it, else
     None: causal self-attention (Sq == Skv, static offset 0), no softcap,
-    no window narrower than the sequence, Hq == Hkv, hd <= 128 or a
-    multiple of 128, a sequence some block divides, and no mesh (the
-    kernel is one device's program; XLA cannot partition it)."""
-    _, S, Hq, hd = q_shape
+    no window narrower than the sequence, Hq == Hkv, a sequence some
+    block divides, and no mesh (the kernel is one device's program; XLA
+    cannot partition it). Any head width goes: ``train_attention`` pads
+    it to one the kernel takes (``kernel_width``)."""
+    _, S, Hq, _ = q_shape
     _, Skv, Hkv, _ = k_shape
     if not (causal and S == Skv and isinstance(q_offset, int)
             and q_offset == 0 and not softcap
             and (window == 0 or window >= S) and Hq == Hkv
-            and (hd <= 128 or hd % 128 == 0) and mesh is None):
+            and mesh is None):
         return None
     return train_block_sizes(S)
 
 
+def kernel_width(dqk: int, dv: int) -> int:
+    """The head width the fused kernel runs q, k and v at: one width for
+    all three, at most 128 or a multiple of 128. Zero columns added to q
+    and k add nothing to the scores; those added to v are sliced off."""
+    w = max(dqk, dv)
+    return w if w <= 128 else -(-w // 128) * 128
+
+
 def train_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
-                    q_offset=0, chunk=512, mesh=None):
-    """Attention of a training forward, (B, S, H, hd) in and out. The
-    platform is decided at lowering, so a compile for a described TPU
-    takes the kernel and every other platform lowers the chunked scan."""
+                    q_offset=0, chunk=512, mesh=None, scale=None):
+    """Attention of a training forward, (B, S, H, d) in and (B, S, H, dv)
+    out, scaled by ``scale`` (default q's width ** -0.5). The platform is
+    decided at lowering, so a compile for a described TPU takes the
+    kernel and every other platform lowers the chunked scan."""
+    dqk, dv = q.shape[-1], v.shape[-1]
+    if scale is None:
+        scale = dqk ** -0.5
+
     def chunked(q, k, v):
         with jax.named_scope("attention.chunked"):
             return chunked_attention(q, k, v, causal=causal, window=window,
                                      softcap=softcap, q_offset=q_offset,
-                                     chunk=chunk)
+                                     chunk=chunk, scale=scale)
 
     blocks = fused_block_sizes(q.shape, k.shape, causal=causal,
                                window=window, softcap=softcap,
@@ -173,9 +192,20 @@ def train_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
 
     def fused(q, k, v):
         with jax.named_scope("attention.flash"):
-            return flash_attention_train(q, k, v, blocks)
+            return fused_attention(q, k, v, blocks, scale)
 
     return jax.lax.platform_dependent(q, k, v, tpu=fused, default=chunked)
+
+
+def fused_attention(q, k, v, blocks, scale):
+    """The Pallas kernel's causal attention at any head widths: q, k and
+    v padded with zeros to ``kernel_width``, the output cut back to v's."""
+    dv = v.shape[-1]
+    width = kernel_width(q.shape[-1], dv)
+    pad = lambda t: t if t.shape[-1] == width else jnp.pad(
+        t, ((0, 0),) * 3 + ((0, width - t.shape[-1]),))
+    out = flash_attention_train(pad(q), pad(k), pad(v), blocks, scale)
+    return out if width == dv else out[..., :dv]
 
 
 def _make_mask(q_pos, kv_pos, causal, window, kv_valid_len):
@@ -212,4 +242,4 @@ def reference_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
     qf = (q.astype(jnp.float32) * hd ** -0.5).reshape(B, Sq, Hkv, Hq // Hkv, hd)
     out = _attend_block(qf, k, v, q_offset + jnp.arange(Sq), jnp.arange(Skv),
                         causal, window, softcap, kv_valid_len)
-    return out.reshape(B, Sq, Hq, hd).astype(q.dtype)
+    return out.reshape(B, Sq, Hq, v.shape[-1]).astype(q.dtype)

@@ -19,8 +19,9 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import schema as mschema
-from repro.models.layers import (attn_block, cross_attn_block, mlp_block,
-                                 norm, shard_act, sinusoidal_positions)
+from repro.models.layers import (attn_block, cross_attn_block, mla_block,
+                                 mlp_block, norm, shard_act,
+                                 sinusoidal_positions)
 from repro.models.moe import moe_block
 from repro.models.ssm import mamba_block
 from repro.models.xlstm import mlstm_block, slstm_block
@@ -33,12 +34,12 @@ def _mixer_window(cfg: ModelConfig, mixer: str, window_override: int) -> int:
     return window_override  # 0 = full attention
 
 
-def _apply_superblock(cfg, mode, mesh, window_override, enc_out,
+def _apply_superblock(cfg, pattern, mode, mesh, window_override, enc_out,
                       bp, csl, x, pos):
-    """Apply one pattern repetition. csl: cache slice (or None)."""
+    """Apply one repetition of ``pattern``. csl: cache slice (or None)."""
     aux = jnp.zeros((), jnp.float32)
     new_cache = {}
-    for i, (mixer, ffn) in enumerate(cfg.pattern):
+    for i, (mixer, ffn) in enumerate(pattern):
         key = f"b{i}_{mixer}"
         p = bp[key]
         c = csl[key] if csl is not None else None
@@ -51,6 +52,9 @@ def _apply_superblock(cfg, mode, mesh, window_override, enc_out,
                                           enc_out=enc_out, cache=c, mesh=mesh)
                 if nc is not None and ncc is not None:
                     nc = {**nc, **ncc}
+        elif mixer == "mla":
+            x, nc = mla_block(cfg, p, x, mode=mode, pos=pos, cache=c,
+                              window=window_override, mesh=mesh)
         elif mixer == "mamba":
             x, nc = mamba_block(cfg, p, x, mode=mode, cache=c, mesh=mesh)
         elif mixer == "mlstm":
@@ -79,12 +83,16 @@ def _apply_superblock(cfg, mode, mesh, window_override, enc_out,
 
 
 def _scan_blocks(cfg, params_stack, cache_stack, x, pos, *, mode, mesh,
-                 window_override, enc_out, remat="none", unroll=False):
+                 window_override, enc_out, remat="none", unroll=False,
+                 pattern=None):
+    pattern = cfg.pattern if pattern is None else pattern
+
     def body(carry, xs):
         x, aux = carry
         bp, csl = xs
-        x, nc, a = _apply_superblock(cfg, mode, mesh, window_override,
-                                     enc_out, bp, csl, x, pos)
+        x, nc, a = _apply_superblock(cfg, pattern, mode, mesh,
+                                     window_override, enc_out, bp, csl, x,
+                                     pos)
         return (x, aux + a), nc
 
     if remat == "full":
@@ -98,8 +106,9 @@ def _scan_blocks(cfg, params_stack, cache_stack, x, pos, *, mode, mesh,
     # unroll=True inlines every repetition: required for exact cost_analysis
     # (XLA's HLO cost model counts a while-loop body ONCE, ignoring the trip
     # count) — the dry-run uses this for the roofline table.
+    n = jax.tree.leaves(params_stack)[0].shape[0]
     (x, aux), new_cache = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                       xs, unroll=cfg.n_repeat if unroll else 1)
+                                       xs, unroll=n if unroll else 1)
     return x, new_cache, aux
 
 
@@ -161,10 +170,21 @@ def forward(cfg: ModelConfig, params, batch: dict, *, mode: str = "train",
         x = jnp.concatenate([img, x], axis=1)
     x = shard_act(x, mesh)
 
-    x, new_cache, aux = _scan_blocks(
-        cfg, params["dec"], cache, x, pos, mode=mode, mesh=mesh,
-        window_override=window_override, enc_out=enc_out, remat=remat,
-        unroll=unroll)
+    scan = partial(_scan_blocks, cfg, pos=pos, mode=mode, mesh=mesh,
+                   window_override=window_override, enc_out=enc_out,
+                   remat=remat, unroll=unroll)
+    first_cache = None
+    if cfg.first_dense:
+        if cache is not None:
+            cache = dict(cache)
+            first_cache = cache.pop("first")
+        x, first_cache, aux0 = scan(params["first"], first_cache, x,
+                                    pattern=cfg.first_pattern)
+    x, new_cache, aux = scan(params["dec"], cache, x)
+    if cfg.first_dense:
+        aux = aux + aux0
+        if new_cache is not None:
+            new_cache = {**new_cache, "first": first_cache}
     return x, new_cache, aux
 
 
@@ -187,35 +207,49 @@ def init_cache(cfg: ModelConfig, batch_size: int, max_seq: int, *,
             return jnp.full(shape, 2 ** 30, jnp.int32)  # invalid position
         return jnp.full(shape, fill, dtype)
 
-    cache = {}
-    for i, (mixer, _) in enumerate(cfg.pattern):
-        key = f"b{i}_{mixer}"
-        if mixer.startswith("attn"):
+    def entry(mixer, R):
+        if mixer.startswith("attn") or mixer == "mla":
             w = _mixer_window(cfg, mixer, window_override)
             L = min(max_seq, w) if w else max_seq
-            ent = {"k": mk((R, B, L, dims.hkv, dims.hd)),
-                   "v": mk((R, B, L, dims.hkv, dims.hd)),
+            if mixer == "mla":     # materialised per-head k and v
+                h, dk, dv = cfg.n_heads, cfg.qk_head_dim, cfg.v_head_dim
+            else:
+                h, dk, dv = dims.hkv, dims.hd, dims.hd
+            ent = {"k": mk((R, B, L, h, dk)), "v": mk((R, B, L, h, dv)),
                    "kpos": mk((R, L), jnp.int32)}
             if cfg.is_encdec:
                 ent["ck"] = mk((R, B, cfg.enc_seq, dims.hkv, dims.hd))
                 ent["cv"] = mk((R, B, cfg.enc_seq, dims.hkv, dims.hd))
-            cache[key] = ent
-        elif mixer == "mamba":
-            cache[key] = {
+            return ent
+        if mixer == "mamba":
+            return {
                 "conv": mk((R, B, cfg.ssm_conv - 1, cfg.ssm_d_inner)),
                 "ssm": mk((R, B, cfg.ssm_d_inner, cfg.ssm_d_state),
                           jnp.float32)}
-        elif mixer == "mlstm":
+        if mixer == "mlstm":
             di = _pad_to(int(cfg.xlstm_pf_mlstm * cfg.d_model), cfg.n_heads)
             hd = di // cfg.n_heads
             H = cfg.n_heads
-            cache[key] = {"C": mk((R, B, H, hd, hd), jnp.float32),
-                          "n": mk((R, B, H, hd), jnp.float32),
-                          "m": mk((R, B, H), jnp.float32, -1e30)}
-        elif mixer == "slstm":
+            return {"C": mk((R, B, H, hd, hd), jnp.float32),
+                    "n": mk((R, B, H, hd), jnp.float32),
+                    "m": mk((R, B, H), jnp.float32, -1e30)}
+        if mixer == "slstm":
             D = cfg.d_model
-            cache[key] = {k: mk((R, B, D), jnp.float32,
-                                -1e30 if k == "m" else 0.0) for k in "cnmh"}
+            return {k: mk((R, B, D), jnp.float32,
+                          -1e30 if k == "m" else 0.0) for k in "cnmh"}
+        return None
+
+    def entries(pattern, R):
+        out = {}
+        for i, (mixer, _) in enumerate(pattern):
+            ent = entry(mixer, R)
+            if ent is not None:
+                out[f"b{i}_{mixer}"] = ent
+        return out
+
+    cache = entries(cfg.pattern, R)
+    if cfg.first_dense:
+        cache["first"] = entries(cfg.first_pattern, cfg.first_dense)
     return cache
 
 
@@ -232,7 +266,7 @@ def cache_specs(cfg: ModelConfig, long_batch_one: bool = False):
     specs = {}
     for i, (mixer, _) in enumerate(cfg.pattern):
         key = f"b{i}_{mixer}"
-        if mixer.startswith("attn"):
+        if mixer.startswith("attn") or mixer == "mla":
             ent = {"k": P(None, batch, seq, "model", None),
                    "v": P(None, batch, seq, "model", None),
                    "kpos": P(None, seq)}
@@ -249,4 +283,7 @@ def cache_specs(cfg: ModelConfig, long_batch_one: bool = False):
                           "m": P(None, batch, None)}
         elif mixer == "slstm":
             specs[key] = {k: P(None, batch, None) for k in "cnmh"}
+    if cfg.first_dense:
+        key = f"b0_{cfg.pattern[0][0]}"
+        specs["first"] = {key: specs[key]}
     return specs

@@ -15,7 +15,18 @@
                     experts replicated, expert d_ff sharded over 'model',
                     every assignment computed locally on the F shard, psum.
 
-Aux loss: Switch-style load-balance  E * sum_e f_e * P_e.
+Routers (``cfg.router``): ``softmax`` (top-k of the softmax, renormed,
+with the Switch-style load-balance aux loss  E * sum_e f_e * P_e) and
+``sigmoid`` (DeepSeek-V3 ``noaux_tc``: top-k of sigmoid scores plus a
+selection bias, gates from the unbiased scores, normed; no aux loss).
+Gates are then scaled by ``cfg.routed_scale``. ``cfg.n_shared_experts``
+adds one shared MLP that every token goes through.
+
+A device may hold only experts [expert_offset, expert_offset +
+experts_held) of ``n_experts`` (one chip's share under expert
+parallelism): ``moe_local`` routes over all of them and computes the held
+experts' part alone, with no exchange; what the absent experts would add
+is left out.
 """
 from __future__ import annotations
 
@@ -23,15 +34,24 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import norm, data_axes
+from repro.models.layers import data_axes, mlp, norm
 
 
 def _router(cfg: ModelConfig, p, x):
-    """x: (T, D) -> top-k probs (T,k), indices (T,k), aux loss scalar."""
+    """x: (T, D) -> top-k gates (T,k), expert ids (T,k), aux loss scalar."""
     logits = (x.astype(jnp.float32)) @ p["router"].astype(jnp.float32)
+    if cfg.router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        # the bias only selects: ids carry no gradient back to it
+        _, top_i = jax.lax.top_k(
+            scores + p["router_bias"].astype(jnp.float32), cfg.top_k)
+        top_p = jnp.take_along_axis(scores, top_i, axis=-1)
+        top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20)
+        return top_p * cfg.routed_scale, top_i, jnp.zeros((), jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_i = jax.lax.top_k(probs, cfg.top_k)
     top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
@@ -40,18 +60,54 @@ def _router(cfg: ModelConfig, p, x):
     f = jnp.mean(jax.nn.one_hot(top_i[:, 0], e, dtype=jnp.float32), axis=0)
     pbar = probs.mean(0)
     aux = e * jnp.sum(f * pbar) * cfg.router_aux_coef
-    return top_p, top_i, aux
+    return top_p * cfg.routed_scale, top_i, aux
 
 
-def _expert_ffn(cfg: ModelConfig, p, xs, group_sizes):
+def _shared(cfg: ModelConfig, p, h):
+    """The shared experts of normed ``h``: one MLP every token takes."""
+    with jax.named_scope("moe.shared"):
+        return mlp(cfg, p, h, prefix="ws")
+
+
+def _expert_ffn(cfg: ModelConfig, p, xs, group_sizes,
+                product=jax.lax.ragged_dot):
     """Grouped FFN over rows of ``xs`` sorted by expert. Weights may be the
     full (E, D, F) stacks or per-rank shards — shapes decide."""
-    up = jax.lax.ragged_dot(xs, p["we_up"], group_sizes)
+    up = product(xs, p["we_up"], group_sizes)
     if cfg.act == "silu":
-        up = jax.nn.silu(jax.lax.ragged_dot(xs, p["we_gate"], group_sizes)) * up
+        up = jax.nn.silu(product(xs, p["we_gate"], group_sizes)) * up
     else:
         up = jax.nn.gelu(up)
-    return jax.lax.ragged_dot(up, p["we_down"], group_sizes)
+    return product(up, p["we_down"], group_sizes)
+
+
+# (m, k, n) tiles of the megablox grouped matmul, swept on a TPU v5e at
+# Moonlight's held share (tools/time_grouped_matmul.py; PERF.md §6)
+GMM_TILING = (1024, 512, 512)
+
+
+def megablox_product(xs, w, sizes, interpret: bool = False):
+    """``_held_product`` through JAX's Pallas megablox ``gmm``: the rows
+    after the G groups are skipped, and zeroed forward and in the rows'
+    gradient."""
+    tm = next((t for t in (GMM_TILING[0], 512, 256, 128)
+               if xs.shape[0] % t == 0), xs.shape[0])
+    return megablox.gmm(xs, w, sizes, preferred_element_type=xs.dtype,
+                        tiling=(tm,) + GMM_TILING[1:], interpret=interpret)
+
+
+def _held_product(xs, w, sizes):
+    """Rows of ``xs`` sorted by group times their group's matrix of ``w``
+    (G, K, N); ``sizes`` (G + 1,) counts each group's rows and then the
+    rows no group takes, which come out zero. On a TPU this is
+    ``megablox_product`` (XLA's ``ragged-dot`` there leaves the rows past
+    the last group unwritten, and its backward is slower); elsewhere
+    ``lax.ragged_dot``."""
+    def other(xs, w, sizes):
+        return jax.lax.ragged_dot(xs, w, sizes[:-1])
+
+    return jax.lax.platform_dependent(xs, w, sizes, tpu=megablox_product,
+                                      default=other)
 
 
 def _sorted_dispatch(cfg, x_flat, top_i, top_p):
@@ -66,18 +122,49 @@ def _sorted_dispatch(cfg, x_flat, top_i, top_p):
     return x_flat[src], gate[order], src, eid[order]
 
 
+def _held_experts(cfg: ModelConfig, p, hf, top_p, top_i):
+    """The held experts' part of the routed output, (T, D). All T*k
+    assignments are sorted with the held ones first, by expert, into a
+    static buffer of T*k rows (so none is dropped, however the router
+    leans); the grouped product covers the held groups, and the foreign
+    rows after them are left out."""
+    t, k = top_i.shape
+    held = cfg.n_held
+    with jax.named_scope("moe.dispatch"):
+        local = top_i.reshape(t * k) - cfg.expert_offset
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)          # foreign -> end
+        order = jnp.argsort(key)
+        src = order // k
+        xs = hf[src]
+        sizes = jnp.bincount(key, length=held + 1).astype(jnp.int32)
+        gate = top_p.reshape(t * k)[order]
+        kept = mine[order]
+    with jax.named_scope("moe.experts"):
+        out = _expert_ffn(cfg, p, xs, sizes, product=_held_product)
+    with jax.named_scope("moe.combine"):
+        out = jnp.where(kept[:, None], out * gate[:, None].astype(out.dtype),
+                        0)
+        return jnp.zeros((t, hf.shape[-1]), out.dtype).at[src].add(out)
+
+
 def moe_local(cfg: ModelConfig, p, x):
-    """Single-device sort-based oracle. x: (B,S,D)."""
-    B, S, D = x.shape
-    h = norm(cfg, p, x)
-    hf = h.reshape(B * S, D)
-    top_p, top_i, aux = _router(cfg, p, hf)
-    xs, gates, src, eid_sorted = _sorted_dispatch(cfg, hf, top_i, top_p)
-    gs = jnp.bincount(eid_sorted, length=cfg.n_experts)
-    out = _expert_ffn(cfg, p, xs.astype(h.dtype), gs)
-    out = out * gates[:, None].astype(out.dtype)
-    y = jnp.zeros((B * S, D), out.dtype).at[src].add(out)
-    return x + y.reshape(B, S, D).astype(x.dtype), aux
+    """Single-device sort-based path, over the experts this device holds
+    (every expert unless ``cfg.experts_held``). x: (B,S,D). With
+    ``cfg.remat_moe`` the backward recomputes the layer's forward instead
+    of keeping its buffers."""
+    def layer(p, x):
+        B, S, D = x.shape
+        h = norm(cfg, p, x)
+        hf = h.reshape(B * S, D)
+        with jax.named_scope("moe.route"):
+            top_p, top_i, aux = _router(cfg, p, hf)
+        y = _held_experts(cfg, p, hf, top_p, top_i).reshape(B, S, D)
+        if cfg.n_shared_experts:
+            y = y + _shared(cfg, p, h).astype(y.dtype)
+        return x + y.astype(x.dtype), aux
+
+    return (jax.checkpoint(layer) if cfg.remat_moe else layer)(p, x)
 
 
 # ---------------------------------------------------------------------------
@@ -203,7 +290,17 @@ def moe_block(cfg: ModelConfig, p, x, mesh=None):
     """Dispatch to the local oracle or a shard_map path based on the mesh."""
     if mesh is None or mesh.shape.get("model", 1) == 1:
         return moe_local(cfg, p, x)
+    if cfg.n_held != cfg.n_experts:
+        raise NotImplementedError(
+            f"{cfg.name}: a share of the experts runs on one device only")
+    y, aux = _moe_sharded(cfg, p, x, mesh)
+    if cfg.n_shared_experts:
+        y = y + _shared(cfg, p, norm(cfg, p, x)).astype(y.dtype)
+    return y, aux
 
+
+def _moe_sharded(cfg: ModelConfig, p, x, mesh):
+    """Routed experts under a model mesh: expert- or tensor-parallel."""
     mp = mesh.shape["model"]
     dp = data_axes(mesh)
     dpsize = 1

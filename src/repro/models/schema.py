@@ -101,6 +101,21 @@ def attn_schema(cfg: ModelConfig, dims: Dims, cross: bool = False) -> dict:
     return sch
 
 
+def mla_schema(cfg: ModelConfig, dims: Dims) -> dict:
+    """Latent attention (``layers.mla_block``): heads are not padded."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    sch = {
+        "wq": ParamDef((d, h * cfg.qk_head_dim), P(None, "model")),
+        "wkv_a": ParamDef((d, r + cfg.qk_rope_head_dim), P()),
+        "wkv_b": ParamDef((r, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                          P(None, "model")),
+        "wo": ParamDef((h * cfg.v_head_dim, d), P("model", None)),
+        "kv_norm_scale": ParamDef((r,), P(), "ones"),
+    }
+    sch.update(_norm_schema(cfg))
+    return sch
+
+
 def mlp_schema(cfg: ModelConfig, dims: Dims) -> dict:
     d, f = cfg.d_model, cfg.d_ff
     sch = {
@@ -114,7 +129,7 @@ def mlp_schema(cfg: ModelConfig, dims: Dims) -> dict:
 
 
 def moe_schema(cfg: ModelConfig, dims: Dims) -> dict:
-    d, f, e = cfg.d_model, cfg.expert_d_ff, cfg.n_experts
+    d, f, e = cfg.d_model, cfg.expert_d_ff, cfg.n_held
     if e >= dims.model_shards and e % max(dims.model_shards, 1) == 0:
         # expert-parallel: experts sharded over 'model'
         espec3 = P("model", None, None)
@@ -124,12 +139,20 @@ def moe_schema(cfg: ModelConfig, dims: Dims) -> dict:
         espec3 = P(None, None, "model")
         dspec3 = P(None, "model", None)
     sch = {
-        "router": ParamDef((d, e), P()),
+        "router": ParamDef((d, cfg.n_experts), P()),
         "we_up": ParamDef((e, d, f), espec3),
         "we_down": ParamDef((e, f, d), dspec3),
     }
     if cfg.act == "silu":
         sch["we_gate"] = ParamDef((e, d, f), espec3)
+    if cfg.router == "sigmoid":
+        sch["router_bias"] = ParamDef((cfg.n_experts,), P(), "zeros")
+    if cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * f
+        sch["ws_up"] = ParamDef((d, fs), P(None, "model"))
+        sch["ws_down"] = ParamDef((fs, d), P("model", None))
+        if cfg.act == "silu":
+            sch["ws_gate"] = ParamDef((d, fs), P(None, "model"))
     sch.update(_norm_schema(cfg))
     return sch
 
@@ -198,6 +221,7 @@ _MIXER_SCHEMAS = {
     "attn": attn_schema,
     "attn_local": attn_schema,
     "attn_global": attn_schema,
+    "mla": mla_schema,
     "mamba": mamba_schema,
     "mlstm": mlstm_schema,
     "slstm": slstm_schema,
@@ -215,6 +239,21 @@ def _stack(sch: dict, n: int) -> dict:
     }
 
 
+def _blocks_schema(cfg: ModelConfig, dims: Dims, pattern, n: int) -> dict:
+    """``pattern``'s blocks, each stacked ``n`` deep."""
+    out: dict = {}
+    for i, (mixer, ffn) in enumerate(pattern):
+        cross = cfg.is_encdec and mixer.startswith("attn")
+        if mixer.startswith("attn"):
+            out[f"b{i}_{mixer}"] = _stack(
+                attn_schema(cfg, dims, cross=cross), n)
+        else:
+            out[f"b{i}_{mixer}"] = _stack(_MIXER_SCHEMAS[mixer](cfg, dims), n)
+        if ffn:
+            out[f"b{i}_{ffn}"] = _stack(_FFN_SCHEMAS[ffn](cfg, dims), n)
+    return out
+
+
 def model_schema(cfg: ModelConfig, model_shards: int = 1) -> dict:
     dims = Dims(cfg, model_shards)
     sch: dict = {
@@ -224,18 +263,10 @@ def model_schema(cfg: ModelConfig, model_shards: int = 1) -> dict:
     }
     sch.update({f"final_{k}": v for k, v in _norm_schema(cfg).items()})
 
-    dec: dict = {}
-    for i, (mixer, ffn) in enumerate(cfg.pattern):
-        cross = cfg.is_encdec and mixer.startswith("attn")
-        if mixer.startswith("attn"):
-            dec[f"b{i}_{mixer}"] = _stack(
-                attn_schema(cfg, dims, cross=cross), cfg.n_repeat)
-        else:
-            dec[f"b{i}_{mixer}"] = _stack(
-                _MIXER_SCHEMAS[mixer](cfg, dims), cfg.n_repeat)
-        if ffn:
-            dec[f"b{i}_{ffn}"] = _stack(_FFN_SCHEMAS[ffn](cfg, dims), cfg.n_repeat)
-    sch["dec"] = dec
+    sch["dec"] = _blocks_schema(cfg, dims, cfg.pattern, cfg.n_repeat)
+    if cfg.first_dense:
+        sch["first"] = _blocks_schema(cfg, dims, cfg.first_pattern,
+                                      cfg.first_dense)
 
     if cfg.is_encdec:
         enc: dict = {

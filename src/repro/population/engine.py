@@ -252,8 +252,10 @@ class Bucket:
         capacity = engine._round_capacity(capacity)
         self.capacity = capacity
         # template state fixes the stacked shapes/dtypes only (zeros;
-        # real state is written per-slot at admission)
-        tmpl = obj.init_slot_state(jax.random.PRNGKey(0), template_hparams)
+        # real state is written per-slot at admission): traced, never built
+        tmpl = jax.eval_shape(
+            lambda k: obj.init_slot_state(k, template_hparams),
+            jax.random.PRNGKey(0))
         zeros = lambda x: jnp.zeros((capacity,) + x.shape, x.dtype)
         self.learner, self.carry = (
             engine._place(jax.tree.map(zeros, t)) for t in tmpl)
@@ -344,10 +346,8 @@ class Bucket:
         """Hot-swap a fresh configuration into slot ``i``. ``traced`` are
         the per-slot hyperparameter scalars in ``hparam_spec().traced``
         order (``PopulationObjective.traced_values``)."""
-        place = self.engine._place
-        setter = lambda a, v: a.at[i].set(v)
-        self.learner = place(jax.tree.map(setter, self.learner, learner))
-        self.carry = place(jax.tree.map(setter, self.carry, carry))
+        self.learner, self.carry = self.engine._write_slot(
+            (self.learner, self.carry), (learner, carry), i)
         for n, v in zip(self.traced_names, traced):
             self.hyper[n][i] = v
         self.active[i] = True
@@ -398,6 +398,17 @@ class Bucket:
                 for a in arrays + (self.active,))
         self.learner, self.carry = self._step(
             self.learner, self.carry, *self._hyper_dev)
+
+
+def _write_slot(stacked, slot, i):
+    """The hot-swap write as ONE program (jitted by the engine, the
+    stacked state donated): slot ``i`` of every leaf of the stacked
+    (learner, carry) overwritten in place, so a swap holds one stacked
+    state and the fresh slot, not two stacked states. ``i`` is traced:
+    one compile per tree and capacity serves every slot."""
+    return jax.tree.map(
+        lambda a, v: jax.lax.dynamic_update_index_in_dim(
+            a, v.astype(a.dtype), i, 0), stacked, slot)
 
 
 @jax.jit
@@ -538,6 +549,10 @@ class PopulationEngine:
             self._sharding = NamedSharding(mesh, PartitionSpec("slots"))
         else:
             self._sharding = None
+        # the hot-swap's slot write: the output keeps the slot sharding
+        self._write_slot = jax.jit(
+            _write_slot, donate_argnums=(0,),
+            **({} if mesh is None else {"out_shardings": self._sharding}))
         # bracket mode: the rung barrier itself lives in the SERVICE (the
         # driver answers "parked" at rung phases); the engine only needs to
         # know it is a bracket participant so ACQUIRE carries the rung-0

@@ -30,7 +30,7 @@ def _batch(r, B, T, seed=0):
 
 
 def test_all_ten_archs_registered():
-    assert len(ARCHS) == 10
+    assert len(ARCHS) == 11
     families = {get_config(a).family for a in ARCHS}
     assert families == {"dense", "moe", "ssm", "hybrid", "encdec", "vlm"}
 

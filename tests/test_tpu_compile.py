@@ -87,6 +87,10 @@ def test_lm_bucket_step_compiles_for_one_chip(one_chip):
     assert compiled.memory_analysis() is not None
 
 
+# the held experts' grouped product on the chip (``models/moe.py``)
+GROUPED_MATMUL = "gmm"
+
+
 def _phi3_1l_objective(seq):
     """The benchmark's ``phi3-mini-3.8b-1L`` objective at ``seq`` tokens."""
     from bench.kinds.lm import build_objective
@@ -105,6 +109,43 @@ def test_phi3_1l_bucket_step_runs_the_flash_kernel(one_chip):
     compiled = _compile_bucket(_phi3_1l_objective(2048), 1024, 2, one_chip)
     _assert_kernel(compiled)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+
+
+def test_moonlight_cell_step_runs_flash_and_grouped_matmul_kernels(
+        one_chip):
+    """The benchmark's ``lm.moonlight.ht1`` step at its own size: one slot
+    of 1 x 8192 tokens through Moonlight-16B-A3B's leading dense layer and
+    four MoE layers on one chip's share. Latent attention, padded to one
+    head width of 256, takes the flash kernels forward and backward; the
+    held experts take the grouped-matmul kernels; and the step with its
+    state fits the chip's 15.75 GiB."""
+    from bench import harness
+    cell = harness.Cell("lm.moonlight.ht1")
+    objective = cell.kind_module().build_objective(cell.config,
+                                                   cell.traffic)
+    compiled = _compile_bucket(objective, 1024, 1, one_chip)
+    text = compiled.as_text()
+    for kernel in ("flash_attention", "flash_mha_bwd_dkv", "flash_mha_bwd_dq",
+                   GROUPED_MATMUL):
+        assert f"%{kernel}" in text or f"_{kernel}" in text, kernel
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75 * 2 ** 30
+
+
+def test_held_experts_take_the_grouped_matmul_kernel_under_vmap(one_chip):
+    """Two slots of the reduced Moonlight model: the held experts' grouped
+    products run the Pallas kernels batched over the slot axis (XLA's
+    ``ragged-dot`` is refused there with a batch dimension). Batched, the
+    kernels' instructions lose their names (``closed_call.N``), so they
+    are counted: 6 flash calls (the dense and the MoE layer, forward and
+    two backward) and the MoE layer's 9 grouped products (3 forward, 3
+    for the rows' gradient, 3 for the weights')."""
+    from repro.population.objectives.lm import LMObjective
+    compiled = _compile_bucket(LMObjective("moonlight-16b-a3b", batch=1,
+                                           seq=512), 512, 2, one_chip)
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 6 + 9
 
 
 def test_ga3c_bucket_step_shard_maps_over_four_chips(topo):

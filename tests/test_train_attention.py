@@ -11,7 +11,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention.train import (flash_attention_train,
                                                  train_block_sizes)
-from repro.models.attention import (chunked_attention, fused_block_sizes,
+from repro.models.attention import (chunked_attention, fused_attention,
+                                    fused_block_sizes, kernel_width,
                                     train_attention)
 
 MHA = dict(S=256, Skv=256, Hq=4, Hkv=4, hd=96, causal=True, window=0,
@@ -33,7 +34,7 @@ DISPATCH = [
     ("not_causal", {"causal": False}, False),
     ("seq_not_a_block_multiple", {"S": 320, "Skv": 320}, False),
     ("seq_below_a_block", {"S": 32, "Skv": 32}, False),
-    ("hd_160", {"hd": 160}, False),
+    ("hd_160", {"hd": 160}, True),
     ("under_a_mesh", {"mesh": "mesh"}, False),
 ]
 
@@ -91,6 +92,48 @@ def test_fused_entry_matches_chunked_attention_interpret():
     assert out.shape == ref.shape and out.dtype == ref.dtype
     assert _rel(out, ref) < 1e-2
     for name, g, r in zip("qkv", grads, ref_grads):
+        assert _rel(g, r) < 1e-2, name
+
+
+@pytest.mark.parametrize("dqk,dv,width", [(96, 96, 96), (128, 128, 128),
+                                          (192, 128, 256), (64, 32, 64),
+                                          (160, 160, 256), (256, 256, 256)])
+def test_kernel_width(dqk, dv, width):
+    """One width for q, k and v, at most 128 or a multiple of 128; a
+    width the kernel takes as it is is not padded (Phi-3's 96)."""
+    assert kernel_width(dqk, dv) == width
+
+
+def test_padded_kernel_matches_chunked_attention_at_mla_widths():
+    """Latent attention's heads (q/k 192 = 128 + 64 RoPE, v 128) through
+    the kernel padded to 256 with the scale 1/sqrt(192) given explicitly,
+    output and gradients against the chunked scan at the native widths
+    (interpret mode)."""
+    B, S, H, dqk, dv = 2, 256, 2, 192, 128
+    rng = np.random.default_rng(1)
+    q, k = (jnp.asarray(rng.standard_normal((B, S, H, dqk)), jnp.bfloat16)
+            for _ in range(2))
+    v = jnp.asarray(rng.standard_normal((B, S, H, dv)), jnp.bfloat16)
+    ct = jnp.asarray(rng.standard_normal((B, S, H, dv)), jnp.float32)
+    blocks = train_block_sizes(128)
+    scale = dqk ** -0.5
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)
+                                       * ct)
+
+    fused = lambda q, k, v: fused_attention(q, k, v, blocks, scale)
+    chunked = lambda q, k, v: chunked_attention(q, k, v, chunk=128,
+                                                scale=scale)
+    with pltpu.force_tpu_interpret_mode():
+        out = fused(q, k, v)
+        grads = jax.grad(loss(fused), argnums=(0, 1, 2))(q, k, v)
+    ref = chunked(q, k, v)
+    ref_grads = jax.grad(loss(chunked), argnums=(0, 1, 2))(q, k, v)
+    assert out.shape == ref.shape == (B, S, H, dv)
+    assert _rel(out, ref) < 1e-2
+    for name, g, r in zip("qkv", grads, ref_grads):
+        assert g.shape == r.shape
         assert _rel(g, r) < 1e-2, name
 
 
