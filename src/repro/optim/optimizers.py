@@ -66,41 +66,66 @@ def _clip_by_global_norm(grads, max_norm):
 
 
 def apply_updates(tc: TrainConfig, params, grads, state: OptState, lr=None,
-                  grad_clip=None, warmup_steps=None):
+                  grad_clip=None, warmup_steps=None, active=None):
     """Returns (new_params, new_state, grad_norm). ``lr``, ``grad_clip``,
     and ``warmup_steps`` (optional traced scalars) override their config
     twins — how the population engine vmaps one train step over per-trial
-    hyperparameters (config values are python floats baked into the jit)."""
+    hyperparameters (config values are python floats baked into the jit).
+
+    ``active`` (optional traced boolean scalar) freezes the update where
+    it is false: every accumulator, parameter and the step counter come
+    back as they went in, bit for bit, whatever the gradient holds (a
+    ``where``, never a multiply). The select sits inside each leaf's
+    update, so XLA's TPU compiler writes a leaf's parameter and
+    accumulators in one fusion, one HBM pass; a mask applied to the
+    outputs afterwards splits that pass in two (the accumulators, then the
+    parameter with them recomputed). The parameter is selected in float32,
+    before its cast back: a select of the bfloat16 value splits the pass
+    too. The cast back is exact, except that a NaN parameter may come back
+    as another NaN."""
     grads, gnorm = _clip_by_global_norm(
         grads, tc.grad_clip if grad_clip is None else grad_clip)
     lr = learning_rate(tc, state.step, base=lr, warmup=warmup_steps)
-    if tc.optimizer == "rmsprop":
-        # non-centered RMSProp: g2 <- d*g2 + (1-d)*g^2 ; p -= lr*g/sqrt(g2+eps)
-        d = tc.rmsprop_decay
-        acc1 = jax.tree.map(lambda a, g: d * a + (1 - d) * g * g,
-                            state.acc1, grads)
-        def upd(p, g, a):
-            return (p.astype(jnp.float32)
-                    - lr * g / jnp.sqrt(a + tc.rmsprop_eps)).astype(p.dtype)
-        new_params = jax.tree.map(upd, params, grads, acc1)
-        return new_params, OptState(state.step + 1, acc1, None), gnorm
+    if active is None:
+        keep = lambda new, old: new
+    else:
+        keep = lambda new, old: jnp.where(active, new, old)
+    with jax.named_scope("optim.update"):
+        if tc.optimizer == "rmsprop":
+            # non-centered RMSProp: g2 <- d*g2 + (1-d)*g^2 ;
+            # p -= lr*g/sqrt(g2+eps)
+            d = tc.rmsprop_decay
+            acc1 = jax.tree.map(lambda a, g: keep(d * a + (1 - d) * g * g, a),
+                                state.acc1, grads)
 
-    # adamw
-    b1, b2 = tc.adam_b1, tc.adam_b2
-    t = state.step + 1
-    m = jax.tree.map(lambda a, g: b1 * a + (1 - b1) * g, state.acc1, grads)
-    v = jax.tree.map(lambda a, g: b2 * a + (1 - b2) * g * g, state.acc2, grads)
-    bc1 = 1 - b1 ** t.astype(jnp.float32)
-    bc2 = 1 - b2 ** t.astype(jnp.float32)
+            def upd(p, g, a):
+                pf = p.astype(jnp.float32)
+                return keep(pf - lr * g / jnp.sqrt(a + tc.rmsprop_eps),
+                            pf).astype(p.dtype)
+            new_params = jax.tree.map(upd, params, grads, acc1)
+            return (new_params,
+                    OptState(keep(state.step + 1, state.step), acc1, None),
+                    gnorm)
 
-    def upd(p, m_, v_):
-        step_ = lr * (m_ / bc1) / (jnp.sqrt(v_ / bc2) + 1e-8)
-        pf = p.astype(jnp.float32)
-        if tc.weight_decay:
-            step_ = step_ + lr * tc.weight_decay * pf
-        return (pf - step_).astype(p.dtype)
+        # adamw
+        b1, b2 = tc.adam_b1, tc.adam_b2
+        t = state.step + 1
+        m = jax.tree.map(lambda a, g: keep(b1 * a + (1 - b1) * g, a),
+                         state.acc1, grads)
+        v = jax.tree.map(lambda a, g: keep(b2 * a + (1 - b2) * g * g, a),
+                         state.acc2, grads)
+        bc1 = 1 - b1 ** t.astype(jnp.float32)
+        bc2 = 1 - b2 ** t.astype(jnp.float32)
 
-    return jax.tree.map(upd, params, m, v), OptState(t, m, v), gnorm
+        def upd(p, m_, v_):
+            step_ = lr * (m_ / bc1) / (jnp.sqrt(v_ / bc2) + 1e-8)
+            pf = p.astype(jnp.float32)
+            if tc.weight_decay:
+                step_ = step_ + lr * tc.weight_decay * pf
+            return keep(pf - step_, pf).astype(p.dtype)
+
+        return (jax.tree.map(upd, params, m, v),
+                OptState(keep(t, state.step), m, v), gnorm)
 
 
 # ---------------------------------------------------------------------------

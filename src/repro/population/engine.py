@@ -57,6 +57,7 @@ Two orthogonal extensions ride on the slot axis:
 """
 from __future__ import annotations
 
+import inspect
 import itertools
 import time
 from dataclasses import dataclass
@@ -443,7 +444,10 @@ def _bucket_step(objective: PopulationObjective, structural: Hashable,
 
     The per-slot body comes from ``objective.make_step``; the engine wraps
     it in vmap over the slot axis, the eviction mask, donation, and (under
-    a mesh) ``shard_map``. A local capacity of 1 skips vmap and squeezes
+    a mesh) ``shard_map``. The mask goes to a step that declares an
+    ``active`` keyword, which freezes its own learner; the engine then
+    masks the carry alone. A step without it has its whole output
+    masked. A local capacity of 1 skips vmap and squeezes
     the slot axis instead, so a single-trial population runs the
     objective's own compact program — for GA3C that is the same XLA
     program as the thread backend (bit-for-bit parity).
@@ -465,24 +469,34 @@ def _bucket_step(objective: PopulationObjective, structural: Hashable,
     local_cap = capacity // n_shards
     n_traced = len(objective.hparam_spec().traced)
     one = objective.make_step(structural, local_cap)
+    # a step that declares ``active`` freezes its own learner where the
+    # slot is masked (inside each leaf's update, one HBM pass a leaf);
+    # any other step gets the mask applied to its whole output here
+    freezes = "active" in inspect.signature(one).parameters
+
+    def one_masked(learner, carry, active, *hyper):
+        if freezes:
+            return one(learner, carry, *hyper, active=active)
+        return one(learner, carry, *hyper)
 
     if local_cap == 1:
-        def batched(learner, carry, *hyper):
+        def batched(learner, carry, active, *hyper):
             squeeze = lambda t: jax.tree.map(lambda x: x[0], t)
-            out = one(squeeze(learner), squeeze(carry),
-                      *(h[0] for h in hyper))
+            out = one_masked(squeeze(learner), squeeze(carry), active[0],
+                             *(h[0] for h in hyper))
             return tuple(jax.tree.map(lambda x: x[None], t) for t in out)
     else:
-        batched = jax.vmap(one)
+        batched = jax.vmap(one_masked)
 
     def step(learner, carry, *rest):
         hyper, active = rest[:-1], rest[-1]
-        new = batched(learner, carry, *hyper)
+        new_learner, new_carry = batched(learner, carry, active, *hyper)
         def keep_active(n, o):
             mask = active.reshape((active.shape[0],) + (1,) * (n.ndim - 1))
             return jnp.where(mask, n, o)
-        return tuple(jax.tree.map(keep_active, n, o)
-                     for n, o in zip(new, (learner, carry)))
+        if not freezes:
+            new_learner = jax.tree.map(keep_active, new_learner, learner)
+        return new_learner, jax.tree.map(keep_active, new_carry, carry)
 
     if mesh is not None:
         from jax.sharding import PartitionSpec

@@ -19,7 +19,13 @@ the ``PopulationObjective`` protocol defined here:
 * ``make_step(structural, local_capacity)`` — the jittable single-slot
   phase step ``(learner, carry, *traced) -> (learner, carry)``; the
   engine vmaps it over the slot axis, applies the eviction mask, donates
-  buffers, and wraps it in ``shard_map`` under a mesh;
+  buffers, and wraps it in ``shard_map`` under a mesh. A step may declare
+  a keyword ``active`` (the slot's traced boolean mask); the engine then
+  passes it and masks only the carry, so such a step must return its
+  whole learner unchanged, bit for bit, where ``active`` is false (the
+  shipped objectives pass it to ``optim.apply_updates``, which freezes
+  params and optimizer state inside each leaf's update). A step without
+  it has its learner and carry masked by the engine;
 * ``progress(carry)``     — two ``(capacity,)`` arrays ``(counts, sums)``
   the host polls to detect phase boundaries (an array read, never a
   device sync per step); the phase metric is ``delta_sum / max(delta_n,

@@ -74,7 +74,7 @@ class GA3CObjective(PopulationObjective):
         unroll = (t_max if (local_capacity > 1 and t_max <= UNROLL_T_MAX)
                   else 1)
 
-        def one(learner, loop, lr, gamma, beta):
+        def one(learner, loop, lr, gamma, beta, active=None):
             params, opt_state = learner
             traj, new_loop = rollout(env, params, loop, t_max, unroll=unroll)
             _, v_boot = apply_net(params, new_loop.obs_stack)
@@ -83,7 +83,8 @@ class GA3CObjective(PopulationObjective):
                 lambda p: a3c_loss(p, traj, v_boot, gamma=gamma, beta=beta),
                 has_aux=True)(params)
             params, opt_state, _ = apply_updates(tc, params, grads,
-                                                 opt_state, lr=lr)
+                                                 opt_state, lr=lr,
+                                                 active=active)
             return (params, opt_state), new_loop
 
         return one

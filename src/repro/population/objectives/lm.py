@@ -103,7 +103,7 @@ class LMObjective(PopulationObjective):
         cfg, tc, table = self.cfg, self.tc, self.table
         batch_size, seq, chunk = self.batch, self.seq, int(structural)
 
-        def one(learner, carry, lr, grad_clip, warmup_steps):
+        def one(learner, carry, lr, grad_clip, warmup_steps, active=None):
             params, opt_state = learner
             rng, k_start, k_choice = jax.random.split(carry["rng"], 3)
             chain = _bigram_chain(table, k_start, k_choice, batch_size, seq)
@@ -117,7 +117,8 @@ class LMObjective(PopulationObjective):
             grads, loss = jax.grad(loss_fn, has_aux=True)(params)
             params, opt_state, _ = apply_updates(
                 tc, params, grads, opt_state, lr=lr,
-                grad_clip=grad_clip, warmup_steps=warmup_steps)
+                grad_clip=grad_clip, warmup_steps=warmup_steps,
+                active=active)
             carry = {"rng": rng, "n": carry["n"] + 1.0,
                      "loss_sum": carry["loss_sum"] - loss}
             return (params, opt_state), carry

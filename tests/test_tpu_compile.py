@@ -9,8 +9,10 @@ The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and
 every test worker imports this file.
 """
+import dataclasses
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -85,6 +87,70 @@ def test_lm_bucket_step_compiles_for_one_chip(one_chip):
     from repro.population.objectives.lm import LMObjective
     compiled = _compile_bucket(LMObjective("yi-9b"), 32, 2, one_chip)
     assert compiled.memory_analysis() is not None
+
+
+# instructions that hand a value on without computing it
+_PASS_ON = ("get-tuple-element", "bitcast", "copy", "copy-start",
+            "copy-done")
+
+
+def _entry(text):
+    """The entry computation of an optimized HLO text: each instruction's
+    name mapped to (opcode, operand names, the whole line), and the name
+    of its root."""
+    body = text[text.index("\nENTRY "):]
+    body = body[:body.index("\n}\n")]
+    instrs, root = {}, None
+    for line in body.splitlines()[1:]:
+        m = re.match(r"\s*(ROOT )?%(\S+) = .*?\b([a-z][a-z-]*)\((.*?)\)"
+                     r"(, |$)", line)
+        if m:
+            instrs[m.group(2)] = (m.group(3),
+                                  re.findall(r"%([\w.-]+)", m.group(4)),
+                                  line)
+            root = m.group(2) if m.group(1) else root
+    return instrs, root
+
+
+def _writers(text):
+    """For each output of the entry computation, in order, the
+    instruction that computes it (past those that only hand it on)."""
+    instrs, root = _entry(text)
+    opcode, outputs, _ = instrs[root]
+    assert opcode == "tuple", opcode
+    found = []
+    for name in outputs:
+        while instrs[name][0] in _PASS_ON:
+            name = instrs[name][1][0]
+        found.append(name)
+    return found, instrs
+
+
+def test_adamw_writes_each_leaf_in_one_fusion(one_chip):
+    """The LM bucket step at reduced widths, with the benchmark's bfloat16
+    weights, at capacity 2: each learner leaf's parameter and both AdamW
+    moments are written by one fusion, the update under the
+    ``optim.update`` scope, so the update reads and writes each leaf once.
+    The engine's select of the step's outputs splits that pass in two
+    (the moments, then the parameter with them recomputed), which is why
+    the step freezes masked slots itself."""
+    from repro.population.objectives.lm import LMObjective
+    objective = LMObjective("yi-9b")
+    objective.cfg = dataclasses.replace(objective.cfg, dtype="bfloat16")
+    compiled = _compile_bucket(objective, 32, 2, one_chip)
+    text = compiled.as_text()
+    writers, instrs = _writers(text)
+    n = len(jax.tree.leaves(jax.eval_shape(
+        objective.init_slot_state, jax.random.PRNGKey(0), {})[0][0]))
+    params, m, v = writers[:n], writers[n + 1:2 * n + 1], \
+        writers[2 * n + 1:3 * n + 1]
+    for p_by, m_by, v_by in zip(params, m, v):
+        opcode, _, line = instrs[m_by]
+        called = re.search(r"calls=%([\w.-]+)", line).group(1)
+        fused = text[text.index(f"\n%{called} "):]
+        assert opcode == "fusion"
+        assert "optim.update" in fused[:fused.index("\n}\n")]
+        assert p_by == m_by == v_by, (p_by, m_by, v_by)
 
 
 # the held experts' grouped product on the chip (``models/moe.py``)
