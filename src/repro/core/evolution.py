@@ -4,7 +4,7 @@ improve the metaoptimization process, for instance ... by mixing the
 hyperparameters of fast learners, or reinitializing terminated agents with
 new sets of promising hyperparameters."
 
-Same DCM/WSM eviction rule as HyperTrick; the difference is ``next_hparams``:
+Same DCM/WSM eviction rule as HyperTrick; the difference is ``_draw``:
 after a warmup fraction of fresh samples, freed nodes restart from a MUTATED
 copy of a top-quartile configuration (PBT-style explore) instead of a fresh
 random sample.
@@ -12,7 +12,6 @@ random sample.
 from __future__ import annotations
 
 import math
-from typing import Optional
 
 from repro.core.hypertrick import HyperTrick
 from repro.core.search_space import SearchSpace, perturb_hparams
@@ -31,10 +30,7 @@ class EvolutionaryHyperTrick(HyperTrick):
         # mid-flight clones — here it seeds a freed node's restart
         return perturb_hparams(self.space, hp, self.rng)
 
-    def next_hparams(self) -> Optional[dict]:
-        if self._launched >= self.w0:
-            return None
-        self._launched += 1
+    def _draw(self) -> dict:
         if self._launched <= self.warmup \
                 or self.rng.uniform() > self.mutate_prob:
             return self.space.sample(self.rng)

@@ -28,9 +28,11 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
-from repro.core.completion import Bracket
-from repro.core.service import (AsyncPolicy, Decision, OptimizationService,
-                                TrialStatus)
+from repro.core.completion import Bracket, demotion_alpha, demotion_bracket
+from repro.core.hypertrick import RandomSearchPolicy
+from repro.core.scheduler import Scheduler
+from repro.core.search_space import SearchSpace
+from repro.core.service import Decision, OptimizationService, TrialStatus
 from repro.launch.runtime import chip_env, count_tpu_chips, repo_root
 
 
@@ -66,7 +68,7 @@ class ExecResult:
         s.update(wall_time=round(self.wall_time, 2),
                  occupancy=round(self.occupancy, 3),
                  alpha=round(self.service.db.completion_rate(
-                     self.service.policy.n_phases), 4))
+                     self.service.scheduler.n_phases), 4))
         if self.extra:
             s.update(self.extra)
         return s
@@ -77,8 +79,8 @@ class ThreadCluster:
         self.n_nodes = n_nodes
         self.objective = objective
 
-    def run(self, policy: AsyncPolicy) -> ExecResult:
-        svc = OptimizationService(policy)
+    def run(self, scheduler: Scheduler) -> ExecResult:
+        svc = OptimizationService(scheduler)
         records: List[ExecRecord] = []
         rec_lock = threading.Lock()
         t0 = time.monotonic()
@@ -89,7 +91,7 @@ class ThreadCluster:
                 if trial is None:
                     return
                 state = None
-                for phase in range(policy.n_phases):
+                for phase in range(scheduler.n_phases):
                     t_start = time.monotonic() - t0
                     try:
                         metric, state = self.objective(trial.hparams, phase,
@@ -258,12 +260,12 @@ class ProcessCluster:
             time.sleep(0.1)
         return [p.wait() for p in procs]
 
-    def run(self, policy: AsyncPolicy) -> ExecResult:
+    def run(self, scheduler: Scheduler) -> ExecResult:
         from repro.distributed.journal import Journal, replay_journal
         from repro.distributed.server import MetaoptServer
 
         self.worker_envs()       # refuse an impossible launch up front
-        svc = OptimizationService(policy, bracket_eta=self.bracket_eta)
+        svc = OptimizationService(scheduler, bracket_eta=self.bracket_eta)
         # a first-class Scheduler (Hyperband) brings its own brackets:
         # workers must join the barrier even without bracket_eta
         self._workers_bracket = svc.barrier is not None
@@ -273,8 +275,7 @@ class ProcessCluster:
         # scheduler), and a fully-parked cohort missing dead capacity
         # resolves after the patience window instead of wedging
         capacity = self.n_nodes * self.slots
-        budget = (getattr(policy, "n_trials", None)
-                  or getattr(policy, "w0", None))
+        budget = getattr(scheduler, "n_trials", None)
         bracket_capacity = (min(capacity, budget) if budget else capacity) \
             if svc.barrier is not None else None
         journal = None
@@ -337,7 +338,7 @@ class PopulationCluster:
     """The on-device population backend: every live trial trains
     simultaneously inside vmapped, jitted GA3C steps
     (``repro.population.engine``), driving the same ``OptimizationService``
-    and policy as every other backend. A "node" is a device slot: eviction
+    and scheduler as every other backend. A "node" is a device slot: eviction
     masks the slot and the next configuration is hot-swapped in, so the
     paper's "stopped worker's node immediately acquires a fresh
     configuration" happens at slot granularity with zero process churn.
@@ -345,7 +346,7 @@ class PopulationCluster:
     ``objective`` selects the workload: None (default) is GA3C on
     ``game``; otherwise a ``PopulationObjective`` instance or a spec dict
     like ``{"kind": "lm", "arch": ...}`` (see ``population.objectives``).
-    ``slots`` defaults to the policy's initial worker count W0 so the
+    ``slots`` defaults to the scheduler's budget (HyperTrick's W0) so the
     entire population is in flight from the first step.
 
     ``devices > 1`` shards every bucket's slot axis across that many
@@ -372,23 +373,21 @@ class PopulationCluster:
         self.bracket_eta = bracket_eta
         self.engine = None      # the last run's engine, for inspection
 
-    def run(self, policy: AsyncPolicy) -> ExecResult:
+    def run(self, scheduler: Scheduler) -> ExecResult:
         from repro.population.engine import LocalDriver, PopulationEngine
-        slots = self.slots or getattr(policy, "w0", None) \
-            or getattr(policy, "n_trials", None) or 8
+        slots = self.slots or getattr(scheduler, "n_trials", None) or 8
         mesh = None
         if self.devices > 1:
             from repro.launch.mesh import make_population_mesh
             mesh = make_population_mesh(self.devices, 1)
         # the rung barrier lives in the service (core.service.RungBarrier):
         # the engine is a thin park/poll client of it, same as remote hosts
-        svc = OptimizationService(policy, bracket_eta=self.bracket_eta)
+        svc = OptimizationService(scheduler, bracket_eta=self.bracket_eta)
         if svc.barrier is not None:
             # single host: the whole entry cohort enrolls in one admission
             # pass before anything can park, so this is consumed instantly
             # — it exists for interface parity with ProcessCluster
-            budget = (getattr(policy, "n_trials", None)
-                      or getattr(policy, "w0", None))
+            budget = getattr(scheduler, "n_trials", None)
             svc.configure_bracket(expect_entrants=(
                 min(slots, budget) if budget else slots))
         engine = self.engine = PopulationEngine(
@@ -406,10 +405,9 @@ class PopulationCluster:
                    for tid, slot, phase, ts, te, metric in rows]
         extra: Dict = {"devices": self.devices}
         if svc.barrier is not None and svc.barrier.rung_log:
-            from repro.core.completion import demotion_alpha, demotion_bracket
             extra["rungs"] = svc.barrier.rung_log
             br = demotion_bracket(slots, self.bracket_eta,
-                                  list(svc.barrier.rungs), policy.n_phases)
+                                  list(svc.barrier.rungs), scheduler.n_phases)
             extra["bracket"] = {"n": br.n, "r": br.r}
             extra["bracket_alpha"] = round(demotion_alpha(br), 4)
         if engine.speculated:
@@ -434,11 +432,9 @@ class SyncCluster:
     def run_sh(self, configs: List[dict], n_phases: int,
                evict_frac: float) -> ExecResult:
         """Vanilla SH: barrier per phase, bottom evict_frac terminated."""
-        from repro.core.hypertrick import RandomSearchPolicy
-        from repro.core.search_space import SearchSpace
-        policy = RandomSearchPolicy(SearchSpace({}), len(configs), n_phases,
-                                    configs=configs)
-        svc = OptimizationService(policy)
+        sampler = RandomSearchPolicy(SearchSpace({}), len(configs), n_phases,
+                                     configs=configs)
+        svc = OptimizationService(sampler)
         trials = [svc.acquire_trial(i % self.n_nodes)
                   for i in range(len(configs))]
         states = {t.trial_id: None for t in trials}

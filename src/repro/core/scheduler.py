@@ -1,14 +1,10 @@
-"""The unified trial-lifecycle Scheduler: ONE verdict pipeline for every
-metaoptimizer in the repo.
+"""The trial-lifecycle Scheduler: the one interface every metaoptimizer
+in the repo implements, and the one verdict pipeline behind it.
 
 The paper frames HyperTrick as one point in a family of population
 metaoptimizers that trade exploration for compute efficiency on a
-distributed system. Before this module, each family member was wired
-through a different layer: HyperTrick/ASHA decided in ``AsyncPolicy
-.on_report``, successive-halving demotion math lived in ``core.asha``,
-parking lived in ``core.service.RungBarrier``, and the population engine
-hot-swapped on raw decision strings. A ``Scheduler`` owns the whole
-lifecycle instead:
+distributed system. A ``Scheduler`` owns the whole lifecycle of a
+family member's trials:
 
 * ``spawn()``                 -> the next configuration (plus which
                                  *bracket* it joins and that bracket's
@@ -20,14 +16,21 @@ lifecycle instead:
 * ``resolve_cohort(...)``     -> which members of a complete rung cohort
                                  are demoted (barrier schedulers only).
 
-``OptimizationService`` and ``MetaoptServer`` dispatch on verdicts; every
-transport (thread cluster, TCP server, on-device population engine) sees
-the same vocabulary. Adding a metaoptimizer is now one subclass:
-``HyperbandScheduler`` (multiple concurrent brackets, cohorts keyed by
-``(bracket_id, rung)``) and ``PBTScheduler`` (exploit/explore via CLONE
-verdicts, executed device-side by the population engine) are both below —
-compare Elfwing et al. (1702.07490) and SEARL (2009.01555) for why
-copy-and-perturb populations matter for deep RL.
+``SampledScheduler`` is the base of the budgeted samplers: HyperTrick,
+random search, ASHA and evolutionary HyperTrick (``core.hypertrick``,
+``core.asha``, ``core.evolution``) and ``PBTScheduler`` below
+(exploit/explore via CLONE verdicts, executed device-side by the
+population engine — compare Elfwing et al. (1702.07490) and SEARL
+(2009.01555) for why copy-and-perturb populations matter for deep RL).
+``BracketScheduler`` puts any scheduler behind one successive-halving
+bracket; ``HyperbandScheduler`` runs multiple concurrent brackets, cohorts
+keyed by ``(bracket_id, rung)``.
+
+Imports point one way: ``search_space`` and ``completion``, then this
+module and the samplers built on it, then ``core.service`` (which
+dispatches on verdicts for every transport: thread cluster, TCP server,
+on-device population engine), then the executors, server and engine
+drivers.
 """
 from __future__ import annotations
 
@@ -38,6 +41,7 @@ from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from repro.core.completion import hyperband_brackets
 from repro.core.search_space import SearchSpace, perturb_hparams
 
 
@@ -53,9 +57,16 @@ class Decision(enum.Enum):
     PARKED = "parked"
 
 
+class TrialStatus(enum.Enum):
+    RUNNING = "running"
+    COMPLETED = "completed"     # ran all phases
+    KILLED = "killed"           # evicted by the scheduler
+    CRASHED = "crashed"         # worker failure (local effect only, §3.2)
+
+
 class VerdictKind(enum.Enum):
     CONTINUE = "continue"
-    STOP = "stop"          # policy eviction / terminal phase
+    STOP = "stop"          # scheduler eviction / terminal phase
     PARK = "park"          # withheld at the rung barrier (poll to resolve)
     DEMOTE = "demote"      # killed by a rung cohort's ranking
     CLONE = "clone"        # PBT exploit/explore: copy a parent, perturb
@@ -93,13 +104,6 @@ Verdict.PARK = Verdict(VerdictKind.PARK)
 Verdict.DEMOTE = Verdict(VerdictKind.DEMOTE)
 
 
-def verdict_of(decision: Decision) -> Verdict:
-    """Lift a legacy ``AsyncPolicy`` decision into the verdict vocabulary."""
-    return {Decision.CONTINUE: Verdict.CONTINUE,
-            Decision.STOP: Verdict.STOP,
-            Decision.PARKED: Verdict.PARK}[decision]
-
-
 class ReportReply(str):
     """A report decision as the worker-side string (``"continue"`` /
     ``"stop"`` / ``"parked"`` — compares equal to plain strings, so every
@@ -124,6 +128,45 @@ class SpawnSpec:
     together only when their ``(bracket_id, rung)`` match."""
     hparams: Dict[str, Any]
     bracket_id: int = 0
+
+
+def rung_phases(n_phases: int, eta: int) -> list:
+    """Rung placement shared by ASHA and the bracket barrier's
+    successive-halving mode: rungs at phase indices eta^0-1, eta^1-1, ...
+    (the final phase completes unconditionally and is never a rung)."""
+    return sorted({min(eta ** i, n_phases) - 1
+                   for i in range(0, 1 + max(1, int(
+                       math.log(max(n_phases, 1), eta)) + 1))})
+
+
+def rung_demotions(n: int, eta: int) -> int:
+    """How many of an ``n``-trial rung cohort are demoted: the bottom
+    ``n // eta``, EXCEPT that a cohort smaller than eta carries too little
+    evidence to demote anyone (ASHA's "not enough evidence" rule, made
+    explicit — ``n // eta`` happens to be 0 there too, but relying on the
+    floor silently was how small-cohort demotion degraded to a no-op).
+    Shared by the service-side ``RungBarrier``, so single-host and
+    multi-host brackets agree by construction."""
+    assert eta >= 2, eta
+    if n < eta:
+        return 0
+    return n // eta
+
+
+def bottom_indices(metrics: list, k: int) -> set:
+    """Indices (into ``metrics``'s order — the cohort's park order) of the
+    bottom ``k`` members: ONE stable ascending argsort over float32
+    metrics (matching the on-device ranking dtype), ties broken by
+    position. The single ranking rule every rung scheduler shares — the
+    bottom-1/eta barrier and Hyperband's keep-top-1/eta both slice it."""
+    order = np.argsort(np.asarray(metrics, np.float32), kind="stable")
+    return set(order[:max(0, k)].tolist())
+
+
+def demote_indices(metrics: list, eta: int) -> set:
+    """The members a bottom-1/eta rung barrier demotes: the bottom
+    ``rung_demotions`` of the stable ranking."""
+    return bottom_indices(metrics, rung_demotions(len(metrics), eta))
 
 
 class Scheduler:
@@ -174,52 +217,73 @@ class Scheduler:
         replay). Budget-accounting subclasses override this."""
 
 
-class PolicyScheduler(Scheduler):
-    """A classic ``AsyncPolicy`` (HyperTrick, random search, ASHA, the
-    evolutionary variant) as a Scheduler: spawn delegates to
-    ``next_hparams``, reports map Decision -> Verdict, nothing ever parks."""
+class SampledScheduler(Scheduler):
+    """A budget of ``n_trials`` configurations that never park: drawn from
+    ``space`` with ``rng``, or taken in order from pre-drawn ``configs``
+    (e.g. to compare against Hyperband on the *same* configurations, paper
+    §5.2.4). Subclasses implement ``on_report`` and may override
+    ``_draw``."""
 
-    brackets: Dict[int, Tuple[int, ...]] = {}
+    def __init__(self, space: SearchSpace, n_trials: int, n_phases: int,
+                 seed: int = 0, configs: Optional[list] = None):
+        self.space = space
+        self.n_trials = n_trials
+        self.n_phases = n_phases
+        self.rng = np.random.default_rng(seed)
+        self._configs = list(configs) if configs is not None else None
+        if self._configs is not None:
+            assert len(self._configs) == n_trials, (
+                f"got {len(self._configs)} configs for {n_trials} trials — "
+                "same-configs comparisons (§5.2.4) require exactly one "
+                "config per trial")
+        self._launched = 0
 
-    def __init__(self, policy):
-        self.policy = policy
-        self.n_phases = policy.n_phases
+    def spawn(self) -> Optional[SpawnSpec]:
+        if self._launched >= self.n_trials:
+            return None
+        self._launched += 1
+        return SpawnSpec(self._draw())
+
+    def _draw(self) -> Dict[str, Any]:
+        """The configuration of the ``_launched``-th spawn."""
+        if self._configs is not None:
+            return self._configs[self._launched - 1]
+        return self.space.sample(self.rng)
+
+    def note_replayed_trial(self, hparams, requeued: bool = False) -> None:
+        if not requeued:
+            self._launched += 1
+
+
+class BracketScheduler(Scheduler):
+    """``inner`` behind ONE successive-halving bracket (id 0): its rung
+    phases park at the service barrier and demote the bottom ``n // eta``
+    of each pooled cohort (ASHA's small-cohort rule included). ``inner``
+    spawns the configurations and may still stop trials between rungs."""
+
+    def __init__(self, inner: Scheduler, eta: int):
+        assert eta >= 2, eta
+        self.inner = inner
+        self.eta = eta
+        self.n_phases = inner.n_phases
+        self.brackets = {0: tuple(p for p in rung_phases(inner.n_phases, eta)
+                                  if p < inner.n_phases - 1)}
 
     def bind(self, db) -> None:
         self.db = db
-        self.policy.bind(db)
+        self.inner.bind(db)
 
     def spawn(self) -> Optional[SpawnSpec]:
-        hp = self.policy.next_hparams()
-        return SpawnSpec(hp) if hp is not None else None
+        return self.inner.spawn()
 
     def on_report(self, trial_id, phase, metric, prior_reports) -> Verdict:
-        return verdict_of(self.policy.on_report(trial_id, phase, metric,
-                                                prior_reports))
-
-    def note_replayed_trial(self, hparams, requeued: bool = False) -> None:
-        self.policy.note_replayed_trial(hparams, requeued)
-
-
-class BracketScheduler(PolicyScheduler):
-    """The PR-4 ``--bracket`` semantics as a Scheduler: ONE successive-
-    halving bracket (id 0) whose rung phases park at the service barrier
-    and demote the bottom ``n // eta`` of each pooled cohort (ASHA's
-    small-cohort rule included). The wrapped policy is the sampler and may
-    still evict between rungs."""
-
-    def __init__(self, policy, eta: int):
-        from repro.core.asha import rung_phases  # scheduler<-asha cycle
-        super().__init__(policy)
-        assert eta >= 2, eta
-        self.eta = eta
-        rungs = tuple(p for p in rung_phases(policy.n_phases, eta)
-                      if p < policy.n_phases - 1)
-        self.brackets = {0: rungs}
+        return self.inner.on_report(trial_id, phase, metric, prior_reports)
 
     def resolve_cohort(self, bracket_id, rung, metrics) -> Set[int]:
-        from repro.core.asha import demote_indices  # scheduler<-asha cycle
         return demote_indices(metrics, self.eta)
+
+    def note_replayed_trial(self, hparams, requeued: bool = False) -> None:
+        self.inner.note_replayed_trial(hparams, requeued)
 
 
 class HyperbandScheduler(Scheduler):
@@ -231,11 +295,10 @@ class HyperbandScheduler(Scheduler):
     ``(bracket_id, rung)`` — so two brackets' cohorts at the same phase
     resolve independently. Demotion is classic SH: keep the top
     ``max(1, n // eta)`` of each cohort (ranking rule shared with the
-    single-bracket barrier via ``core.asha.bottom_indices``)."""
+    single-bracket barrier via ``bottom_indices``)."""
 
     def __init__(self, space: SearchSpace, n_phases: int, eta: int = 3,
                  seed: int = 0, plan=None):
-        from repro.core.completion import hyperband_brackets
         assert eta >= 2, eta
         self.space = space
         self.n_phases = n_phases                 # R, in phases
@@ -265,7 +328,6 @@ class HyperbandScheduler(Scheduler):
         return Verdict.CONTINUE                  # all decisions are rungs'
 
     def resolve_cohort(self, bracket_id, rung, metrics) -> Set[int]:
-        from repro.core.asha import bottom_indices  # scheduler<-asha cycle
         keep = max(1, len(metrics) // self.eta)
         return bottom_indices(metrics, len(metrics) - keep)
 
@@ -304,7 +366,7 @@ class HyperbandScheduler(Scheduler):
                 return
 
 
-class PBTScheduler(Scheduler):
+class PBTScheduler(SampledScheduler):
     """Population Based Training as a Scheduler: a fixed population runs
     every phase; a member whose phase metric falls in the bottom
     ``exploit_frac`` quantile of that phase's reports receives a CLONE
@@ -322,32 +384,20 @@ class PBTScheduler(Scheduler):
     same knowledge-DB-quantile shape as HyperTrick's WSM rule.
     """
 
-    brackets: Dict[int, Tuple[int, ...]] = {}
-
     def __init__(self, space: SearchSpace, population: int, n_phases: int,
                  seed: int = 0, exploit_frac: float = 0.25,
                  top_frac: float = 0.25, min_reports: Optional[int] = None,
                  frozen: Sequence[str] = ("t_max",)):
         assert 0 < exploit_frac < 1 and 0 < top_frac <= 1
-        self.space = space
+        super().__init__(space, population, n_phases, seed=seed)
         self.population = population
-        self.n_trials = population               # budget, for capacity math
-        self.n_phases = n_phases
-        self.rng = np.random.default_rng(seed)
         self.exploit_frac = exploit_frac
         self.top_frac = top_frac
         self.min_reports = (min_reports if min_reports is not None
                             else max(2, population // 2))
         self.frozen = tuple(frozen)
-        self._launched = 0
         # (trial_id, clone_from, phase) per CLONE verdict issued
         self.clone_log: List[Tuple[int, int, int]] = []
-
-    def spawn(self) -> Optional[SpawnSpec]:
-        if self._launched >= self.population:
-            return None
-        self._launched += 1
-        return SpawnSpec(self.space.sample(self.rng))
 
     def on_report(self, trial_id, phase, metric, prior_reports) -> Verdict:
         if phase >= self.n_phases - 1:
@@ -372,15 +422,10 @@ class PBTScheduler(Scheduler):
         return Verdict(VerdictKind.CLONE, clone_from=parent.trial_id,
                        perturb=hp)
 
-    def note_replayed_trial(self, hparams, requeued: bool = False) -> None:
-        if not requeued:
-            self._launched += 1
-
     def _pick_parent(self, trial_id: int, phase: int):
         """A uniform draw from the top ``top_frac`` of the members that
         have reported this phase (crashed trials excluded — their learner
         state is gone)."""
-        from repro.core.service import TrialStatus  # scheduler<-service
         peers = [t for t in self.db.trials.values()
                  if t.trial_id != trial_id and t.phases_completed > phase
                  and t.status is not TrialStatus.CRASHED]

@@ -3,16 +3,12 @@
 A thread-safe service backed by a central knowledge database. Workers
 (threads or simulated nodes) acquire trials, report a metric at the end of
 each phase, and are told whether to continue — exactly the worker protocol
-of paper §3.1/§3.2. The metaoptimizer is pluggable two ways:
-
-* a classic ``AsyncPolicy`` (HyperTrick, random search, ASHA, ...) — the
-  service wraps it in a ``core.scheduler.PolicyScheduler`` (or a
-  ``BracketScheduler`` when ``bracket_eta`` is given, reproducing the
-  PR-4 single-bracket barrier);
-* a first-class ``core.scheduler.Scheduler`` (Hyperband, PBT) passed
-  directly — the service dispatches on the ``Verdict``s it returns and
-  builds a ``RungBarrier`` over whatever ``(bracket_id, rung)`` cohorts
-  the scheduler declares.
+of paper §3.1/§3.2. The metaoptimizer is a ``core.scheduler.Scheduler``
+(HyperTrick, random search, ASHA, Hyperband, PBT, ...): the service
+dispatches on the ``Verdict``s it returns and builds a ``RungBarrier``
+over whatever ``(bracket_id, rung)`` cohorts it declares. Given
+``bracket_eta``, the service puts the scheduler behind one
+successive-halving bracket (``core.scheduler.BracketScheduler``).
 """
 from __future__ import annotations
 
@@ -22,19 +18,9 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
-from repro.core.scheduler import (BracketScheduler, Decision,
-                                  PolicyScheduler, Scheduler, Verdict,
-                                  VerdictKind)
+from repro.core.scheduler import (BracketScheduler, Decision, Scheduler,
+                                  TrialStatus, Verdict, VerdictKind)
 from repro.telemetry.metrics import MetricsRegistry
-
-import enum
-
-
-class TrialStatus(enum.Enum):
-    RUNNING = "running"
-    COMPLETED = "completed"     # ran all phases
-    KILLED = "killed"           # evicted by the policy
-    CRASHED = "crashed"         # worker failure (local effect only, §3.2)
 
 
 @dataclass
@@ -43,7 +29,7 @@ class TrialRecord:
     hparams: Dict[str, Any]
     status: TrialStatus = TrialStatus.RUNNING
     node: Optional[int] = None
-    # config re-issued after a reclaimed lease (did not consume policy budget)
+    # config re-issued after a reclaimed lease (did not charge the budget)
     requeued: bool = False
     # which scheduler bracket the trial belongs to (Hyperband runs several
     # concurrently; single-bracket and bracketless searches use 0)
@@ -358,41 +344,16 @@ class RungBarrier:
         return out
 
 
-class AsyncPolicy:
-    """A metaoptimization policy for asynchronous execution. Subclasses:
-    HyperTrick, RandomSearchPolicy. (New-style metaoptimizers subclass
-    ``core.scheduler.Scheduler`` instead and own the whole lifecycle.)"""
-
-    n_phases: int = 1
-
-    def bind(self, db: KnowledgeDB):
-        self.db = db
-
-    def next_hparams(self) -> Optional[Dict[str, Any]]:
-        """Next configuration to explore, or None when the budget is spent."""
-        raise NotImplementedError
-
-    def on_report(self, trial_id: int, phase: int, metric: float,
-                  prior_reports: int) -> Decision:
-        raise NotImplementedError
-
-    def note_replayed_trial(self, hparams: Dict[str, Any],
-                            requeued: bool = False):
-        """A trial issued by a previous incarnation of the service (journal
-        replay). Budget-accounting subclasses override this."""
-
-
 class OptimizationService:
     """Thread-safe facade the workers talk to (report / acquire / query).
 
-    ``policy`` may be a classic ``AsyncPolicy`` (wrapped in a
-    ``PolicyScheduler``, or a ``BracketScheduler`` when ``bracket_eta`` is
-    given) or a first-class ``Scheduler`` used as-is. Every lifecycle
-    decision flows through ONE verdict pipeline: the scheduler's
+    ``scheduler`` is used as is, or put behind one successive-halving
+    bracket (``BracketScheduler``) when ``bracket_eta`` is given. Every
+    lifecycle decision flows through ONE verdict pipeline: the scheduler's
     ``Verdict`` is applied here (statuses, clone hparam swaps, barrier
     bookkeeping) and mapped to the transport ``Decision`` for workers."""
 
-    def __init__(self, policy, clock=time.monotonic,
+    def __init__(self, scheduler: Scheduler, clock=time.monotonic,
                  bracket_eta: Optional[int] = None, metrics=None):
         self.db = KnowledgeDB()
         # telemetry: latencies in real seconds (time.perf_counter — the cost
@@ -400,19 +361,13 @@ class OptimizationService:
         # clock seconds (domain time — meaningful in trace replay too).
         # Pass ``telemetry.NULL_REGISTRY`` to opt out entirely.
         self.metrics = metrics if metrics is not None else MetricsRegistry()
-        if isinstance(policy, Scheduler):
-            assert bracket_eta is None, (
-                "a Scheduler declares its own brackets; bracket_eta only "
-                "wraps classic AsyncPolicy instances")
-            self.scheduler: Scheduler = policy
-        elif bracket_eta is not None:
-            self.scheduler = BracketScheduler(policy, bracket_eta)
-        else:
-            self.scheduler = PolicyScheduler(policy)
+        if bracket_eta is not None:
+            assert not scheduler.brackets, (
+                "this scheduler declares its own brackets; bracket_eta "
+                "only wraps schedulers that never park")
+            scheduler = BracketScheduler(scheduler, bracket_eta)
+        self.scheduler = scheduler
         self.scheduler.bind(self.db)
-        # the object summaries/launchers introspect (n_phases, w0, ...):
-        # the original policy when wrapped, the scheduler itself otherwise
-        self.policy = policy
         self.clock = clock
         self._lock = threading.RLock()
         self._next_id = 0
@@ -428,7 +383,7 @@ class OptimizationService:
 
     def requeue(self, hparams: Dict[str, Any], bracket_id: int = 0):
         """Re-issue a configuration whose worker died (lease expired): the
-        budget slot goes back to the pool without charging the policy."""
+        budget slot goes back to the pool without charging the scheduler."""
         with self._lock:
             self._requeue.append((hparams, bracket_id))
             self.metrics.counter("service.requeues").inc()
@@ -707,7 +662,7 @@ class OptimizationService:
 
     def stop_trial(self, trial_id: int):
         """Executor-driven eviction (a client-side ``demote`` report):
-        mark a RUNNING trial KILLED — same terminal status a policy STOP
+        mark a RUNNING trial KILLED — same terminal status a scheduler STOP
         decision produces, but decided outside ``on_report``."""
         with self._lock:
             rec = self.db.trials[trial_id]

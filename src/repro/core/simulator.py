@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.core.completion import Bracket
 from repro.core.hypertrick import HyperTrick
+from repro.core.search_space import SearchSpace
 from repro.core.service import (Decision, OptimizationService, TrialStatus)
 
 
@@ -186,7 +187,6 @@ def simulate_hypertrick(workload: Workload, configs: Sequence[dict],
     speeds = list(node_speeds or [1.0] * n_nodes)
     rng = np.random.default_rng(seed + 999)
     clock = [0.0]
-    from repro.core.search_space import SearchSpace
     policy = HyperTrick(SearchSpace({}), w0, n_phases, eviction_rate,
                         seed=seed, configs=list(configs))
     svc = (service_factory or OptimizationService)(

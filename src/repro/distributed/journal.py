@@ -130,7 +130,7 @@ def read_full_history(path: str) -> Iterator[dict]:
 def replay_journal(path: str, service: OptimizationService,
                    journal: Optional[Journal] = None,
                    reclaim_running: bool = True) -> int:
-    """Rebuild ``service`` (db + id counter + policy budget accounting +
+    """Rebuild ``service`` (db + id counter + scheduler budget accounting +
     requeue queue) from the journal at ``path``. Returns the number of
     events applied; 0 if the file does not exist.
 

@@ -7,7 +7,7 @@ Two tiers, one stats shape:
   and drive them through every phase, reporting either one
   ``report_batch`` frame per generation (``batched=True``) or one classic
   ``report`` round-trip per trial — the batched-vs-per-trial comparison
-  ``benchmarks/server_load.py`` turns into BENCH_server_load.json.
+  ``tests/load/test_server_load.py`` runs.
 * ``run_sim_load`` — the *scale* tier: ``replay_trace`` drives the REAL
   ``OptimizationService``/``RungBarrier`` with a 1000-host synthetic
   trace on a simulated clock, so "thousands of workers" runs in seconds

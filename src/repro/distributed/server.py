@@ -356,7 +356,7 @@ class MetaoptServer:
         if isinstance(msg, proto.SummaryRequest):
             s = st.service.db.summary()
             s["alpha"] = round(st.service.db.completion_rate(
-                st.service.policy.n_phases), 4)
+                st.service.scheduler.n_phases), 4)
             return proto.SummaryResponse(summary=s)
         if isinstance(msg, proto.StatsRequest):
             # live telemetry snapshot (service + server metrics share one
@@ -403,7 +403,7 @@ class MetaoptServer:
             return min(1.0, self.lease_ttl / 2) if st.leases else None
 
     def _do_acquire(self, st: _Search, msg: proto.AcquireRequest):
-        n_phases = st.service.policy.n_phases
+        n_phases = st.service.scheduler.n_phases
         recs = self._grant(st, msg.node,
                            int(getattr(msg, "slots", 1) or 1),
                            getattr(msg, "rung", None),
@@ -424,7 +424,7 @@ class MetaoptServer:
                                      bracket_id=recs[0].bracket_id or None)
 
     def _do_acquire_batch(self, st: _Search, msg: proto.AcquireBatchRequest):
-        n_phases = st.service.policy.n_phases
+        n_phases = st.service.scheduler.n_phases
         recs = self._grant(st, msg.node,
                            int(getattr(msg, "slots", 1) or 1),
                            getattr(msg, "rung", None),

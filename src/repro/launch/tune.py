@@ -27,8 +27,9 @@ import json
 
 from repro.core.executor import (PopulationCluster, ProcessCluster,
                                  ThreadCluster)
-from repro.core.hypertrick import HyperTrick, RandomSearchPolicy
 from repro.core.completion import expected_alpha, min_alpha
+from repro.core.hypertrick import HyperTrick, RandomSearchPolicy
+from repro.core.scheduler import HyperbandScheduler, PBTScheduler
 from repro.core.search_space import (LogUniform, SearchSpace, lm_space,
                                      paper_rl_space)
 from repro.launch.runtime import use_compile_cache
@@ -61,14 +62,12 @@ def main():
     ap.add_argument("--episodes-per-phase", type=int, default=60)
     ap.add_argument("--steps-per-phase", type=int, default=25)
     ap.add_argument("--synthetic-sleep", type=float, default=0.05)
-    ap.add_argument("--policy", choices=["hypertrick", "random"],
-                    default="hypertrick")
-    ap.add_argument("--scheduler",
+    ap.add_argument("--scheduler", "--policy", dest="scheduler",
                     choices=["hypertrick", "random", "hyperband", "pbt"],
-                    default=None,
+                    default="hypertrick",
                     help="trial-lifecycle scheduler (core.scheduler): "
-                         "hypertrick/random keep the classic async "
-                         "policies (same results as --policy); hyperband "
+                         "hypertrick/random are asynchronous samplers that "
+                         "never park (random never stops a trial); hyperband "
                          "runs EVERY bracket of the (eta, R=--phases) "
                          "construction concurrently through the service's "
                          "rung barrier, cohorts keyed by (bracket_id, "
@@ -105,8 +104,9 @@ def main():
                          "bottom 1/eta is demoted. On --backend vectorized "
                          "the cohort is the local population; on process/"
                          "server ONE bracket spans every worker process "
-                         "(cohorts pool across hosts). The service policy "
-                         "becomes a pure sampler (--policy is ignored)")
+                         "(cohorts pool across hosts). The scheduler "
+                         "becomes a pure random sampler (--scheduler "
+                         "hypertrick/random is ignored)")
     ap.add_argument("--eta", type=int, default=3,
                     help="rung demotion factor for --bracket (default 3)")
     ap.add_argument("--n-envs", type=int, default=16,
@@ -130,8 +130,8 @@ def main():
     else:
         space = synthetic_space()
 
-    scheduler = args.scheduler or args.policy
-    if scheduler == "hyperband":
+    kind = args.scheduler
+    if kind == "hyperband":
         if args.bracket:
             ap.error("--scheduler hyperband IS a bracket scheduler (every "
                      "(eta, R) bracket runs concurrently); drop --bracket")
@@ -139,34 +139,32 @@ def main():
             ap.error("--scheduler hyperband pools its bracket cohorts at "
                      "the server-side rung barrier; use --backend process "
                      "or server")
-        from repro.core.scheduler import HyperbandScheduler
-        policy = HyperbandScheduler(space, n_phases=args.phases,
-                                    eta=args.eta, seed=args.seed)
-    elif scheduler == "pbt":
+        scheduler = HyperbandScheduler(space, n_phases=args.phases,
+                                       eta=args.eta, seed=args.seed)
+    elif kind == "pbt":
         if args.bracket:
             ap.error("--scheduler pbt is asynchronous (no rung barrier); "
                      "drop --bracket")
-        from repro.core.scheduler import PBTScheduler
         from repro.population.objectives import spec_for
         # perturb rules come from the OBJECTIVE: its structural keys (rl:
         # t_max, lm: loss_chunk) stay frozen under CLONE perturbation —
         # a perturbed structural value would silently re-bucket (rl) or
         # recompile (lm) the child
-        policy = PBTScheduler(space, population=args.workers,
-                              n_phases=args.phases, seed=args.seed,
-                              frozen=spec_for(args.objective).structural)
+        scheduler = PBTScheduler(space, population=args.workers,
+                                 n_phases=args.phases, seed=args.seed,
+                                 frozen=spec_for(args.objective).structural)
     elif args.bracket:
         # rung demotion needs a pure sampler upstream: the W0
         # configurations come from the service, every eviction decision is
         # the barrier's ranking
-        policy = RandomSearchPolicy(space, args.workers, args.phases,
-                                    seed=args.seed)
-    elif scheduler == "hypertrick":
-        policy = HyperTrick(space, args.workers, args.phases,
-                            args.eviction_rate, seed=args.seed)
+        scheduler = RandomSearchPolicy(space, args.workers, args.phases,
+                                       seed=args.seed)
+    elif kind == "hypertrick":
+        scheduler = HyperTrick(space, args.workers, args.phases,
+                               args.eviction_rate, seed=args.seed)
     else:
-        policy = RandomSearchPolicy(space, args.workers, args.phases,
-                                    seed=args.seed)
+        scheduler = RandomSearchPolicy(space, args.workers, args.phases,
+                                       seed=args.seed)
 
     if args.backend != "vectorized" and args.devices > 1:
         ap.error("--devices drives the on-device population engine; use "
@@ -175,7 +173,7 @@ def main():
         ap.error("--bracket needs the service-side rung barrier; use "
                  "--backend vectorized (one host) or process/server "
                  "(multi-host brackets)")
-    if (args.bracket or scheduler == "hyperband") and args.eta < 2:
+    if (args.bracket or kind == "hyperband") and args.eta < 2:
         ap.error("--eta must be >= 2 (demote bottom 1/eta per rung)")
 
     if args.backend == "vectorized":
@@ -239,7 +237,7 @@ def main():
                                  bracket_eta=(args.eta if args.bracket
                                               else None))
 
-    result = cluster.run(policy)
+    result = cluster.run(scheduler)
     summary = result.summary()
     summary["expected_alpha"] = expected_alpha(args.eviction_rate, args.phases)
     summary["min_alpha"] = min_alpha(args.eviction_rate, args.phases)

@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.scheduler import rung_demotions
 from repro.population.objectives import (PopulationObjective,
                                          objective_from_spec)
 from repro.population.objectives.ga3c import UNROLL_T_MAX  # noqa: F401
@@ -99,7 +100,7 @@ class LocalDriver:
         """Up to ``k`` fresh leases. ``(leases, retry)``: ``retry`` is None
         when an empty result is final (budget spent), else seconds to wait
         before polling again. ``rung`` is the bracket-refill hint."""
-        n_phases = getattr(self.service.policy, "n_phases", None)
+        n_phases = self.service.scheduler.n_phases
         leases = []
         for slot in range(k):
             rec = self.service.acquire_trial(rung=rung)
@@ -709,7 +710,6 @@ class PopulationEngine:
                         # barrier round-trip (the service resolves any
                         # ready cohort before enrolling them, so they land
                         # in the next generation)
-                        from repro.core.asha import rung_demotions
                         want = (self.max_slots
                                 + rung_demotions(self._n_parked(),
                                                  self.bracket_eta)

@@ -85,12 +85,12 @@ class TraceResult:
                 "rungs": len(self.rung_log)}
 
 
-def replay_trace(policy, workload, hosts: Sequence[HostSpec], *,
+def replay_trace(scheduler, workload, hosts: Sequence[HostSpec], *,
                  bracket_eta: Optional[int] = None, lease_ttl: float = 15.0,
                  seed: int = 0, metrics=None, journal=None,
                  entrant_patience: Optional[float] = None,
                  max_sim_s: float = 1e7) -> TraceResult:
-    """Run ``policy`` over ``hosts`` against a real OptimizationService on
+    """Run ``scheduler`` over ``hosts`` against a real OptimizationService on
     a simulated clock. ``journal`` (anything with ``append(dict)``, e.g.
     ``distributed.journal.Journal``) additionally receives the standard
     event stream with simulated ``ts`` stamps, dashboard-ready.
@@ -103,12 +103,11 @@ def replay_trace(policy, workload, hosts: Sequence[HostSpec], *,
     cohorts (the ``worker_exit`` path)."""
     metrics = metrics if metrics is not None else MetricsRegistry()
     now = [0.0]
-    svc = OptimizationService(policy, clock=lambda: now[0],
+    svc = OptimizationService(scheduler, clock=lambda: now[0],
                               bracket_eta=bracket_eta, metrics=metrics)
     rung_hint = 0 if svc.barrier is not None else None
     if svc.barrier is not None:
-        budget = (getattr(policy, "n_trials", None)
-                  or getattr(policy, "w0", None))
+        budget = getattr(scheduler, "n_trials", None)
         cap = min(len(hosts), budget) if budget else len(hosts)
         svc.configure_bracket(
             expect_entrants=cap,

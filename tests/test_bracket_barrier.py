@@ -11,9 +11,9 @@ import warnings
 
 import pytest
 
-from repro.core.asha import demote_indices, rung_demotions
 from repro.core.executor import ProcessCluster
 from repro.core.hypertrick import RandomSearchPolicy
+from repro.core.scheduler import demote_indices, rung_demotions
 from repro.core.search_space import LogUniform, SearchSpace
 from repro.core.service import (Decision, OptimizationService, TrialStatus)
 from repro.distributed import protocol as proto

@@ -1,10 +1,10 @@
 """Dedicated coverage for ``core.evolution.EvolutionaryHyperTrick``,
-exercised through the unified Scheduler pipeline (the service wraps it in
-a ``PolicyScheduler`` and every decision flows as a ``Verdict``)."""
+exercised through the Scheduler pipeline (the service uses it as its
+scheduler and every decision flows as a ``Verdict``)."""
 import numpy as np
 
 from repro.core.evolution import EvolutionaryHyperTrick
-from repro.core.scheduler import PolicyScheduler, VerdictKind
+from repro.core.scheduler import VerdictKind
 from repro.core.search_space import (Categorical, LogUniform, QLogUniform,
                                      SearchSpace)
 from repro.core.service import Decision, OptimizationService, TrialStatus
@@ -22,7 +22,7 @@ def test_warmup_spawns_are_fresh_samples():
                                     warmup_frac=0.5, mutate_prob=1.0)
     twin = np.random.default_rng(0)
     svc = OptimizationService(policy)
-    assert isinstance(svc.scheduler, PolicyScheduler)
+    assert svc.scheduler is policy
     for _ in range(policy.warmup):
         rec = svc.acquire_trial()
         assert rec.hparams == SPACE.sample(twin)  # same seed, same draws

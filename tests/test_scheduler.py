@@ -10,8 +10,8 @@ import pytest
 from repro.core.executor import ProcessCluster
 from repro.core.hypertrick import HyperTrick, RandomSearchPolicy
 from repro.core.scheduler import (BracketScheduler, HyperbandScheduler,
-                                  PBTScheduler, PolicyScheduler, ReportReply,
-                                  SpawnSpec, Verdict, VerdictKind)
+                                  PBTScheduler, ReportReply, SpawnSpec,
+                                  Verdict, VerdictKind)
 from repro.core.search_space import (Categorical, LogUniform, SearchSpace,
                                      perturb_hparams)
 from repro.core.service import (Decision, OptimizationService, TrialStatus)
@@ -54,7 +54,7 @@ def test_report_reply_is_a_decision_string_with_payload():
 def test_policy_scheduler_wraps_classic_policies():
     policy = HyperTrick(_space(), w0=3, n_phases=2, eviction_rate=0.25)
     svc = OptimizationService(policy)
-    assert isinstance(svc.scheduler, PolicyScheduler)
+    assert svc.scheduler is policy
     assert svc.barrier is None                   # async: nothing ever parks
     recs = [svc.acquire_trial() for _ in range(3)]
     assert svc.acquire_trial() is None
@@ -65,6 +65,7 @@ def test_bracket_scheduler_reproduces_single_bracket():
     policy = RandomSearchPolicy(_space(), 4, 4, seed=0)
     svc = OptimizationService(policy, bracket_eta=3)
     assert isinstance(svc.scheduler, BracketScheduler)
+    assert svc.scheduler.inner is policy
     assert svc.barrier.brackets == {0: tuple(svc.barrier.rungs)}
     assert svc.scheduler.resolve_cohort(0, 0, [3.0, 1.0, 2.0]) == {1}
 
