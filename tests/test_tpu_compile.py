@@ -167,14 +167,40 @@ def _phi3_1l_objective(seq):
     return build_objective(config, {"batch": 1, "seq": seq})
 
 
-def test_phi3_1l_bucket_step_runs_the_flash_kernel(one_chip):
+@pytest.fixture(scope="module")
+def phi3_1l_step(one_chip):
+    """The benchmark's ``lm.phi3.ht2`` bucket step: two slots of 1 x 2048
+    tokens at Phi-3-mini widths, loss chunk 1024."""
+    return _compile_bucket(_phi3_1l_objective(2048), 1024, 2, one_chip)
+
+
+def test_phi3_1l_bucket_step_runs_the_flash_kernel(phi3_1l_step):
     """Two slots of 1 x 2048 tokens at Phi-3-mini widths (32 heads of 96,
     MHA, causal): attention takes the Pallas kernel, whose working set is
     a fraction of the chunked scan's float32 score blocks (6.65 GiB of
     temporaries with the scan)."""
-    compiled = _compile_bucket(_phi3_1l_objective(2048), 1024, 2, one_chip)
-    _assert_kernel(compiled)
-    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+    _assert_kernel(phi3_1l_step)
+    assert phi3_1l_step.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+
+
+def test_phi3_1l_loss_keeps_no_logit_stash(phi3_1l_step):
+    """The LM head and loss (``train/steps.py:lm_loss``) compute each
+    chunk's gradients in the forward scan. Autodiff through the scan kept
+    every chunk's float32 exponentials, f32[2 chunks, 2 slots, 1, 1024,
+    32064], for a reverse loop, and its derivative of the gold-logit
+    gather was a scatter; with both gone the step needs fewer temporaries
+    than its 3,066,229,760 bytes."""
+    text = phi3_1l_step.as_text()
+    vocab, chunks = 32064, 2048 // 1024
+    assert "loss.xent" in text
+    for tuple_type in re.findall(r"= \((.*?)\) while\(", text):
+        for dims in re.findall(r"f32\[([\d,]+)\]", tuple_type):
+            dims = [int(d) for d in dims.split(",")]
+            assert not (dims[0] == chunks and dims[-1] == vocab), dims
+    for line in text.splitlines():
+        assert not (" scatter(" in line and "loss.xent" in line), line
+    assert phi3_1l_step.memory_analysis().temp_size_in_bytes \
+        < 3_066_229_760
 
 
 def test_moonlight_cell_step_runs_flash_and_grouped_matmul_kernels(
